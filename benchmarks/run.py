@@ -45,6 +45,8 @@ BENCHES = [
 def main():
     import importlib
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     want = sys.argv[1:]
     results = {}
     failures = 0

@@ -1,119 +1,75 @@
-"""jax version-compatibility shims — the single place that knows which
-jax API surface is installed.
+"""The jax API seam — the single place that names the jax spellings the
+codebase depends on (the installed jax is 0.9.0).
 
-The codebase targets the modern jax API (``jax.shard_map``,
-``jax.lax.pcast``, ``AbstractMesh(axis_sizes, axis_names)``); older
-releases (e.g. 0.4.x, as shipped in some containers) spell these
-``jax.experimental.shard_map.shard_map`` (with ``check_rep`` instead of
-``check_vma``), have no ``pcast`` (no varying-manual-axes bookkeeping to
-satisfy), and construct ``AbstractMesh`` from a tuple of (name, size)
-pairs.  Every module that needs one of these goes through this file, so
-a jax upgrade/downgrade is a one-file change.
+Every module that needs ``shard_map``, ``pcast``, ``axis_size``, the
+async-collective split or ``AbstractMesh`` goes through this file, so
+a jax upgrade is a one-file change.
 """
 
 from __future__ import annotations
 
-import jax
+import math
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_PCAST = hasattr(jax.lax, "pcast")
-# Async (start/finish split) collectives: no released jax exposes them
-# as stable lax primitives yet (XLA performs the split internally via
-# its latency-hiding scheduler), so this probes for the experimental
-# spelling and otherwise reports False — callers then fall back to
-# eager-issue + identity-finish, which is value-identical (see
-# ``async_*`` below and DESIGN.md Sec. 16).
-_HAS_ASYNC_COLLECTIVES = hasattr(jax.lax, "all_gather_start") and \
-    hasattr(jax.lax, "all_gather_finish")
+import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with graceful fallback to the experimental API.
-
-    On old jax the ``check_vma`` knob maps to ``check_rep=False``: the
-    0.4.x replication checker predates the varying-manual-axes model and
-    rejects valid programs that the modern checker accepts (e.g. psum
-    results consumed at different manual-axis subsets)."""
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental import shard_map as _sm
-    return _sm.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """``jax.shard_map``.  ``check_vma`` stays on unless one program
+    states why its output is replicated where the checker cannot
+    prove it (see ``inv_trsm.it_inv_phase1_sharded``)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pcast_varying(x, axes):
-    """``jax.lax.pcast(x, axes, to="varying")`` where it exists; the
-    identity elsewhere (pre-vma jax has no varying/replicated types to
-    reconcile, so the cast is purely bookkeeping)."""
-    if _HAS_PCAST:
-        return jax.lax.pcast(x, axes, to="varying")
-    return x
+    """``jax.lax.pcast(x, axes, to="varying")``."""
+    return jax.lax.pcast(x, axes, to="varying")
+
+
+def out_struct_like(shape, dtype, like):
+    """``ShapeDtypeStruct`` carrying ``like``'s varying manual axes, so a
+    ``pallas_call`` output composes inside shard_map bodies."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (new jax) or the classic constant-folded
-    ``psum(1, axis)`` idiom (0.4.x), which returns a concrete int for a
-    unit constant.  Accepts a single name or a tuple of names."""
-    if hasattr(jax.lax, "axis_size"):
-        import math
-        if isinstance(axis_name, (tuple, list)):
-            return int(math.prod(jax.lax.axis_size(a) for a in axis_name))
-        return int(jax.lax.axis_size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
+    """``jax.lax.axis_size`` of a single name or a tuple of names."""
+    names = axis_name if isinstance(axis_name, (tuple, list)) \
+        else (axis_name,)
+    return int(math.prod(jax.lax.axis_size(a) for a in names))
 
 
-def has_async_collectives() -> bool:
-    """Whether the installed jax can express a true start/finish
-    collective split.  False on every 0.4.x (and, at the time of
-    writing, every released) jax: there the ``async_*_start`` shims
-    below issue the collective eagerly and ``async_finish`` is the
-    identity — the VALUES are identical either way, and XLA's
-    latency-hiding scheduler is still free to overlap the issued
-    collective with any data-independent compute between start and
-    finish (DESIGN.md Sec. 16)."""
-    return _HAS_ASYNC_COLLECTIVES
+# jax 0.9.0 has no start/finish collective split (XLA performs it
+# internally via its latency-hiding scheduler), so the ``async_*_start``
+# shims below issue the collective eagerly and ``async_*_finish`` is the
+# identity — the VALUES are identical either way, and the scheduler is
+# still free to overlap the issued collective with any data-independent
+# compute between start and finish (DESIGN.md Sec. 16).
 
 
 def async_all_gather_start(x, axis_name, *, axis: int = 0,
                            tiled: bool = False):
-    """Begin an all-gather; returns an opaque handle for
-    :func:`async_finish`.  True split where jax exposes one, else the
-    eager synchronous gather (the handle is then just the result)."""
-    if _HAS_ASYNC_COLLECTIVES:
-        return jax.lax.all_gather_start(x, axis_name, axis=axis,
-                                        tiled=tiled)
+    """Begin an all-gather; returns the handle for
+    :func:`async_all_gather_finish` (here: the gathered value)."""
     return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
 def async_all_gather_finish(handle):
     """Complete an all-gather started by :func:`async_all_gather_start`."""
-    if _HAS_ASYNC_COLLECTIVES:
-        return jax.lax.all_gather_finish(handle)
     return handle
 
 
 def async_ppermute_start(x, axis_name, perm):
-    """Begin a ppermute; returns an opaque handle for
-    :func:`async_finish`.  Same fallback contract as the gather."""
-    if _HAS_ASYNC_COLLECTIVES:
-        return jax.lax.ppermute_start(x, axis_name, perm=perm)
+    """Begin a ppermute; same contract as the gather."""
     return jax.lax.ppermute(x, axis_name, perm=perm)
 
 
 def async_ppermute_finish(handle):
     """Complete a ppermute started by :func:`async_ppermute_start`."""
-    if _HAS_ASYNC_COLLECTIVES:
-        return jax.lax.ppermute_finish(handle)
     return handle
 
 
 def abstract_mesh(axis_sizes, axis_names, **kw):
-    """``AbstractMesh`` across the 0.4.x -> 0.5+ signature change:
-    new jax wants ``(axis_sizes, axis_names)``, 0.4.x wants a single
-    ``shape_tuple`` of (name, size) pairs."""
+    """``AbstractMesh(axis_sizes, axis_names)``."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names), **kw)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)), **kw)
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names), **kw)

@@ -67,12 +67,29 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import precision as preclib
 from repro.core import session as sessionlib
 from repro.core.grid import TrsmGrid
 from repro.core.session import CompiledSolverCache, SolverProgram
+
+
+def as_factor(L, dtype=None):
+    """A factor as given: a device array stays put, anything else
+    becomes a host NumPy array — never a default-device copy, which
+    would sit on the chip next to the distributed copies for the whole
+    admission (a chip-sized f32 factor is a quarter of its HBM)."""
+    if isinstance(L, jax.Array):
+        return L if dtype is None else L.astype(dtype)
+    return np.asarray(L) if dtype is None else np.asarray(L, dtype)
+
+
+# (...) -> (1, ...) with the source donated: XLA aliases the buffer, so
+# a chip-sized factor is never on the device twice, as it is next to an
+# eager ``a[None]`` copy.
+_width1 = jax.jit(lambda a: a[None], donate_argnums=0)
 
 
 class FactorBank:
@@ -326,7 +343,7 @@ class FactorBank:
         the leading d x k solution block is bit-identical to an
         unpadded order-d solve at the same n0 (DESIGN.md Sec. 12).
         Capacity banks only."""
-        L = jnp.asarray(L)
+        L = as_factor(L)
         pad_from = self._resolve_pad(L, pad_to)
         self._check_square(L, 2, order=pad_from)
         if self.capacity is not None:
@@ -335,7 +352,15 @@ class FactorBank:
                                          self.transpose, self.policy,
                                          structure=self.structure,
                                          n0=self.n0)
-        self._append(self._entry(tuple(p(L) for p in preps)))
+        if not isinstance(L, jax.Array):
+            # one upload for every prep (a refining policy has two)
+            L = jax.device_put(L, NamedSharding(self.grid.mesh, P()))
+        parts = tuple(p(L) for p in preps)
+        del L
+        # the upload is freed once the preps finish: wait for that
+        # before phase 1 allocates its scratch next to the copies
+        jax.block_until_ready(parts)
+        self._append(self._entry(parts))
         return self.size - 1
 
     def admit_stack(self, Ls):
@@ -347,7 +372,7 @@ class FactorBank:
         role (plus one stacked phase-1 inversion); a partially-filled
         capacity bank falls back to per-slot admission through the
         compiled updater."""
-        Ls = jnp.asarray(Ls)
+        Ls = as_factor(Ls)
         self._check_square(Ls, 3)
         M = Ls.shape[0]
         if self.capacity is not None:
@@ -415,14 +440,17 @@ class FactorBank:
         dts = (self.policy.storage_dtype,)
         if self.policy.refines:
             dts += (self.policy.residual_dtype,)
-        parts = tuple(jax.device_put(jnp.asarray(L_cyc, dt), sharding)
+        # copies: _append donates them, never the caller's buffer
+        parts = tuple(jax.device_put(jnp.asarray(L_cyc, dt), sharding,
+                                     may_alias=False)
                       for dt in dts)
         self._append(self._entry(parts))
         return self.size - 1
 
     def _append(self, entry: tuple) -> None:
-        """Admit one factor: a chunk of width 1."""
-        self._append_chunk(tuple(a[None] for a in entry), 1)
+        """Admit one factor: a chunk of width 1.  The entry's arrays
+        are donated into it."""
+        self._append_chunk(tuple(_width1(a) for a in entry), 1)
 
     def _append_chunk(self, stacks: tuple, count: int) -> None:
         self._chunks.append(stacks)
@@ -500,6 +528,13 @@ class FactorBank:
                 self.update_spec(ingest, chunk=chunk, pad_from=pad_from),
                 self.cache)
             self._updaters[memo] = prog
+        # jit keys its trace on the input's sharding: a host factor and
+        # a place_factor'd one would each trace the updater
+        spec = P(*(None,) * L.ndim) if ingest == "natural" \
+            else P(*(None,) * (L.ndim - 2), *self.grid.spec_L())
+        sh = NamedSharding(self.grid.mesh, spec)
+        if getattr(L, "sharding", None) != sh:
+            L = jax.device_put(L, sh)
         self._stacks = prog.update(self.stacks(), self._slot_id(slot), L)
         self.updates_dispatched += 1
 

@@ -33,6 +33,7 @@ from repro.core import blocked, comm
 from repro.core import tri_inv as ti
 from repro.core.grid import TrsmGrid
 from repro.core.mm3d import mm3d_shard
+from repro.core.precision import gemm_precision
 
 MESH_AXES = ("x", "y", "z")
 
@@ -46,16 +47,19 @@ def chol_blocked_local(A: jnp.ndarray, bs: int) -> jnp.ndarray:
     assert n % bs == 0, (n, bs)
     nb = n // bs
     L = jnp.zeros_like(A)
+    hp = gemm_precision(A)
     for j in range(nb):
         s0, s1 = j * bs, (j + 1) * bs
         Ljl = L[s0:s1, :s0]
-        Ajj = A[s0:s1, s0:s1] - Ljl @ Ljl.T
+        Ajj = A[s0:s1, s0:s1] - jnp.matmul(Ljl, Ljl.T, precision=hp)
         Ljj = jnp.linalg.cholesky(Ajj)
         L = L.at[s0:s1, s0:s1].set(Ljj)
         if s1 < n:
-            Pj = A[s1:, s0:s1] - L[s1:, :s0] @ Ljl.T
+            Pj = A[s1:, s0:s1] - jnp.matmul(L[s1:, :s0], Ljl.T,
+                                            precision=hp)
             Ljj_inv = blocked.tri_inv_doubling(Ljj)
-            L = L.at[s1:, s0:s1].set(Pj @ Ljj_inv.T)
+            L = L.at[s1:, s0:s1].set(
+                jnp.matmul(Pj, Ljj_inv.T, precision=hp))
     return L
 
 

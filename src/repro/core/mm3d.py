@@ -44,6 +44,7 @@ from repro import compat
 
 from repro.core import comm
 from repro.core.grid import TrsmGrid, to_cyclic_matrix, from_cyclic_matrix
+from repro.core.precision import gemm_precision
 
 
 def _swap_perm(p1: int) -> list[tuple[int, int]]:
@@ -88,7 +89,8 @@ def mm3d_shard(Lloc: jnp.ndarray, Xloc: jnp.ndarray, *,
     #    class, cols = this z-slice.
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None \
         else Xloc.dtype
-    Pp = jax.lax.dot(Lg, Xg, preferred_element_type=acc)     # (ml, k/p2)
+    Pp = jax.lax.dot(Lg, Xg, precision=gemm_precision(Lg, Xg),
+                     preferred_element_type=acc)             # (ml, k/p2)
 
     # 5. complete the contraction over y; keep col-chunk x' == y, which
     #    is exactly the input cyclic layout.

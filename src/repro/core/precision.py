@@ -38,13 +38,30 @@ an fp32 sweep (the factor is never touched in fp64 by the sweep).
 A policy is hashable and lands verbatim in the
 ``CompiledSolverCache`` key, so every distinct precision configuration
 compiles (and retraces) exactly once per solve shape.
+
+Every GEMM in the solve stack passes ``precision=gemm_precision(a, b)``:
+on a TPU the default precision for f32 operands is ONE bf16 pass, which
+would serve bf16-grade answers from the ``fp32`` preset and cap the
+``bf16_refine`` residual at bf16 accuracy.  f32/f64 operands therefore
+run at ``HIGHEST``; bf16 operands keep the default (native MXU input).
 """
 
 from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
+
+
+def gemm_precision(*operands):
+    """The matmul ``precision=`` for GEMM operands (arrays or dtypes):
+    ``HIGHEST`` when the promoted operand dtype is f32 or wider, None
+    (the default) below that."""
+    dt = jnp.result_type(*operands)
+    if jnp.issubdtype(dt, jnp.floating) and jnp.finfo(dt).bits >= 32:
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -38,7 +38,7 @@ import jax.lax
 import jax.numpy as jnp
 
 from repro.core import grid as gridlib
-from repro.core.precision import PrecisionPolicy
+from repro.core.precision import PrecisionPolicy, gemm_precision
 
 
 def apply_cyclic_operator(L_cyc, X, *, p1: int, p2: int, reverse: bool,
@@ -64,14 +64,15 @@ def apply_cyclic_operator(L_cyc, X, *, p1: int, p2: int, reverse: bool,
     """
     Xg = gridlib.cyclic_rows_device(X, p1 * p2, reverse=reverse)
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None else X.dtype
+    Xg = Xg.astype(L_cyc.dtype)
+    hp = gemm_precision(L_cyc, Xg)
     if L_cyc.ndim == 2:
-        Y = jax.lax.dot(L_cyc, Xg.astype(L_cyc.dtype),
+        Y = jax.lax.dot(L_cyc, Xg, precision=hp,
                         preferred_element_type=acc)
     else:
         Y = jax.lax.dot_general(
-            L_cyc, Xg.astype(L_cyc.dtype),
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=acc)
+            L_cyc, Xg, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            precision=hp, preferred_element_type=acc)
     return gridlib.cyclic_rows_device(Y, p1, inverse=True, reverse=reverse)
 
 

@@ -445,8 +445,57 @@ def check_overlap() -> int:
     return fails
 
 
+def check_phase1_z() -> int:
+    """The phase-1 program runs with the vma check off because its
+    out_specs claim Dt is replicated over z (see
+    ``inv_trsm.it_inv_phase1_sharded``).  Assert that claim: on every
+    mesh a four-chip host allows, for every phase-1 mode, the z-replica
+    shards of Dt are BIT-equal — and the assembled Dt is the cyclic
+    storage of the inverted diagonal blocks."""
+    from jax.sharding import NamedSharding
+    from repro.core import grid as gridlib
+    from repro.core import inv_trsm, tri_inv
+
+    jax.config.update("jax_enable_x64", True)
+    fails = 0
+    n = 64
+    L = _random_tril(4, n)
+    for (p1, p2) in [(1, 1), (1, 2), (1, 4), (2, 1)]:
+        grid = gridlib.make_trsm_mesh(p1, p2)
+        Lc = jax.device_put(gridlib.to_cyclic_matrix(L, p1, p1 * p2),
+                            NamedSharding(grid.mesh, grid.spec_L()))
+        for n0 in (8, 16, 32):
+            m = n // n0
+            s0 = min(tri_inv.pick_s0(n, p1, p2), n0)
+            modes = ["allgather"]
+            if m % grid.p == 0:
+                modes.append("alltoall")
+            if s0 % (p1 * p2) == 0 and (n0 // s0) & (n0 // s0 - 1) == 0:
+                modes.append("doubling")
+            want = np.stack([
+                gridlib.to_cyclic_matrix(np.linalg.inv(
+                    L[i * n0:(i + 1) * n0, i * n0:(i + 1) * n0]), p1, p1)
+                for i in range(m)])
+            for mode in modes:
+                Dt = jax.jit(inv_trsm.it_inv_phase1_sharded(
+                    grid, n, n0, mode=mode))(Lc)
+                replicas: dict = {}
+                for sh in Dt.addressable_shards:
+                    replicas.setdefault(str(sh.index), []).append(
+                        np.asarray(sh.data).tobytes())
+                bit = all(len(set(r)) == 1 for r in replicas.values())
+                err = np.abs(np.asarray(Dt) - want).max()
+                ok = bit and err < 1e-10
+                print(f"phase1_z p1={p1} p2={p2} n0={n0} mode={mode}: "
+                      f"z-replicas bit-equal={bit} err={err:.2e} "
+                      f"{'OK' if ok else 'FAIL'}")
+                fails += 0 if ok else 1
+    return fails
+
+
 CHECKS = {
     "order": check_collective_order,
+    "phase1_z": check_phase1_z,
     "it_inv_trsm": check_it_inv_trsm,
     "mm3d": check_mm3d,
     "tri_inv": check_tri_inv,

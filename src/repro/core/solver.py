@@ -58,7 +58,7 @@ import jax.numpy as jnp
 
 from repro.core import errors as _errors
 from repro.core import precision as preclib
-from repro.core.bank import FactorBank
+from repro.core.bank import FactorBank, as_factor
 from repro.core.grid import TrsmGrid
 from repro.core.precision import PrecisionPolicy
 from repro.core.structure import FactorStructure
@@ -532,7 +532,7 @@ class Solver:
         the factor's block structure (DESIGN.md Sec. 14): admission
         masks to it, the sweep skips outside it, and the n0 argmin
         prices it."""
-        L = jnp.asarray(L) if dtype is None else jnp.asarray(L, dtype)
+        L = as_factor(L, dtype)
         if L.ndim != 2 or L.shape[0] != L.shape[1]:
             raise ValueError(f"factor must be square, got {L.shape}")
         n = L.shape[0]
@@ -544,7 +544,8 @@ class Solver:
         bank = FactorBank(grid, n, method=method, n0=n0, mode=mode,
                           lower=lower, transpose=transpose,
                           machine=machine, block_inv=block_inv,
-                          dtype=None if precision is not None else L.dtype,
+                          dtype=None if precision is not None
+                          else jax.dtypes.canonicalize_dtype(L.dtype),
                           precision=precision, map_mode=map_mode,
                           structure=structure, overlap=overlap,
                           cache=cache)
@@ -567,7 +568,7 @@ class Solver:
         LIVE-MUTABLE bank at width C: the compiled program is keyed on
         C, so later ``replace_factor``/``evict_factor``/``admit_factor``
         churn never retraces (DESIGN.md Sec. 11)."""
-        Ls = jnp.asarray(Ls) if dtype is None else jnp.asarray(Ls, dtype)
+        Ls = as_factor(Ls, dtype)
         if Ls.ndim != 3 or Ls.shape[-1] != Ls.shape[-2]:
             raise ValueError(f"factor stack must be (M, n, n), got "
                              f"{Ls.shape}")
@@ -575,7 +576,7 @@ class Solver:
                           mode=mode, lower=lower, transpose=transpose,
                           machine=machine, block_inv=block_inv,
                           dtype=None if precision is not None
-                          else Ls.dtype,
+                          else jax.dtypes.canonicalize_dtype(Ls.dtype),
                           precision=precision, map_mode=map_mode,
                           capacity=capacity, structure=structure,
                           overlap=overlap, cache=cache)
@@ -735,6 +736,10 @@ class Solver:
         semantics) donates the RHS buffer."""
         B, squeeze = self._lift(B)
         prog = self.program_for(B.shape[-1])
+        if getattr(B, "sharding", None) != prog.rhs_sharding:
+            # jit keys its trace on the input's sharding: an unplaced
+            # panel and a place_rhs'd one would each trace the program
+            B = jax.device_put(B, prog.rhs_sharding)
         fn = prog.solve_donating if donate else prog.solve
         X = fn(self.bank.stacks(), B)
         self.solves_served += self.width

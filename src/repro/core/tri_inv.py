@@ -52,18 +52,7 @@ def _diag_pieces(Lloc, m: int):
     """(nl, ncl) local cyclic piece -> (m, nl/m, ncl/m) local pieces of
     the m diagonal blocks."""
     nl, ncl = Lloc.shape
-    V = Lloc.reshape(m, nl // m, m, ncl // m)
-    idx = jnp.arange(m)
-    return V[idx, :, idx, :]
-
-
-def _set_diag_pieces(Lloc, pieces):
-    nl, ncl = Lloc.shape
-    m, a, b = pieces.shape
-    V = Lloc.reshape(m, a, m, b)
-    idx = jnp.arange(m)
-    V = V.at[idx, :, idx, :].set(pieces)
-    return V.reshape(nl, ncl)
+    return blocked.diag_blocks(Lloc, nl // m, ncl // m)
 
 
 def _assemble_blocks(Dg, p1: int, p2: int):
@@ -118,7 +107,7 @@ def _invert_diag_blocks_inplace(Lloc, *, n, s0, p1, p2, block_inv, mode):
         S = _pieces_for_all(binv, p1, p2)          # (p, mb, a, b)
         Dt = comm.all_to_all(S.reshape(m0, *S.shape[2:]), MESH_AXES,
                              split_axis=0, concat_axis=0, tiled=True)
-        return _set_diag_pieces(Lloc, Dt)
+        return blocked.set_diag_blocks(Lloc, Dt)
     elif mode == "allgather":
         xi = comm.axis_index("x")
         yi = comm.axis_index("y")
@@ -127,7 +116,7 @@ def _invert_diag_blocks_inplace(Lloc, *, n, s0, p1, p2, block_inv, mode):
         blocks = _assemble_blocks(Dg, p1, p2)      # (m0, s0, s0)
         binv = block_inv(blocks)
         piece = _cyclic_piece(binv, xi, yi, zi, p1, p2)
-        return _set_diag_pieces(Lloc, piece)
+        return blocked.set_diag_blocks(Lloc, piece)
     raise ValueError(mode)
 
 
@@ -147,7 +136,7 @@ def _doubling_levels(Lloc, *, n, s0, s_hi, p1, p2):
         T = mm3d_shard_batched(l21, a11, m=s, n=s, k=s, p1=p1, p2=p2)
         new21 = -mm3d_shard_batched(a22, T, m=s, n=s, k=s, p1=p1, p2=p2)
         blk = blk.at[:, al // 2:, : bl // 2].set(new21)
-        Lloc = _set_diag_pieces(Lloc, blk)
+        Lloc = blocked.set_diag_blocks(Lloc, blk)
         s *= 2
     return Lloc
 
@@ -222,7 +211,7 @@ def block_diag_inv_shard(Lloc, *, n, n0, p1, p2, s0=None, block_inv=None,
             sub = sub.at[:, idx, :, idx, :].set(d)
             blk = sub.reshape(nb, al, bl)
             s *= 2
-        Lloc = _set_diag_pieces(Lloc, blk)
+        Lloc = blocked.set_diag_blocks(Lloc, blk)
     return Lloc
 
 
@@ -232,8 +221,9 @@ def tri_inv_fn(grid: TrsmGrid, n: int, s0: int | None = None,
     body = functools.partial(tri_inv_shard, n=n, p1=grid.p1, p2=grid.p2,
                              s0=s0, block_inv=block_inv, mode=mode)
     spec = P("x", ("z", "y"))
+    # off only for a Pallas hook: see inv_trsm.it_inv_trsm_sharded
     fn = compat.shard_map(body, mesh=grid.mesh, in_specs=(spec,),
-                       out_specs=spec, check_vma=block_inv is None)
+                          out_specs=spec, check_vma=block_inv is None)
     return jax.jit(fn)
 
 

@@ -60,13 +60,15 @@ def trsm_substitution(L, B, bn: int = 128, accum_dtype=jnp.float32,
 
 def block_inv_kernel(blocks: jnp.ndarray) -> jnp.ndarray:
     """Hook matching the ``block_inv`` signature of the distributed
-    solvers: (m, n0, n0) -> batched inverses, Pallas-backed when the
-    block size is a power of two (>= 2), pure-jnp doubling otherwise.
+    solvers: (m, n0, n0) -> batched inverses by the Pallas kernel.
 
-    Degenerate blocks are rejected eagerly: a zero-sized batch or a
-    0x0 / non-square block would otherwise flow into the Pallas grid
-    with a 0-extent dimension and fail deep inside Mosaic (or silently
-    produce an empty program)."""
+    Blocks the kernel does not take are rejected eagerly, never
+    rerouted to another inverter: a zero-sized batch or a 0x0 /
+    non-square block would otherwise flow into the Pallas grid with a
+    0-extent dimension and fail deep inside Mosaic (or silently produce
+    an empty program), and the doubling levels need a power-of-two
+    block size.  Callers with other block sizes pass
+    ``repro.core.blocked.tri_inv_batched`` instead."""
     if blocks.ndim != 3:
         raise ValueError(
             f"block_inv_kernel expects a (m, n0, n0) stack of blocks, "
@@ -80,7 +82,9 @@ def block_inv_kernel(blocks: jnp.ndarray) -> jnp.ndarray:
         raise ValueError(
             f"degenerate block batch {blocks.shape}: zero-sized batches "
             f"cannot be inverted — check n0 / grid divisibility upstream")
-    if n0 & (n0 - 1) == 0 and n0 >= 2:
-        return _tib.tri_inv_blocks(blocks, interpret=_interpret())
-    from repro.core import blocked
-    return blocked.tri_inv_batched(blocks)
+    if n0 & (n0 - 1):
+        raise ValueError(
+            f"block size {n0} is not a power of two: the Pallas inverter "
+            f"takes power-of-two blocks only (use "
+            f"repro.core.blocked.tri_inv_batched for this n0)")
+    return _tib.tri_inv_blocks(blocks, interpret=_interpret())

@@ -1,7 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth that tests/test_kernels.py sweeps against
-(shapes x dtypes, interpret=True execution of the kernels on CPU)."""
+(shapes x dtypes, interpret=True execution of the kernels on CPU) and
+that chip_smoke.py compares the compiled kernels with on the chip —
+hence f32 matmuls at HIGHEST precision (a TPU's default is one bf16
+pass)."""
 
 from __future__ import annotations
 
@@ -11,7 +14,7 @@ import jax.numpy as jnp
 
 def trmm_ref(L: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
     """C = tril(L) @ X."""
-    return jnp.tril(L) @ X
+    return jnp.matmul(jnp.tril(L), X, precision=jax.lax.Precision.HIGHEST)
 
 
 def tri_inv_blocks_ref(Ls: jnp.ndarray) -> jnp.ndarray:

@@ -14,76 +14,44 @@ All log2(n0) levels execute on one VMEM-resident tile, so the block is
 read from HBM exactly once and written once — arithmetic intensity
 n0/3 flops/byte at the HBM level, vs O(1) for row-by-row substitution.
 The first level (1x1 diagonal) is a vectorized reciprocal on the VPU;
-every other level is MXU work.
+every other level is two full-tile MXU matmuls (masked operands, so
+the tile never needs a gather or an unaligned slice).
 
 Grid: one block per grid step (the stack dimension); block sizes up to
-512 fit VMEM (3 * n0^2 * 4B well under 16 MiB).  n0 must be a power of
-two (the Diagonal-Inverter guarantees this by construction).
+512 fit VMEM (a few n0^2 * 4B temporaries, well under 16 MiB).  n0
+must be a power of two (the Diagonal-Inverter guarantees this by
+construction).
 """
 
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-
-def _doubling_inverse(L: jnp.ndarray,
-                      accum_dtype=jnp.float32) -> jnp.ndarray:
-    """In-VMEM bottom-up doubling inversion of one (n0, n0) tile.
-    Static python loop over log2(n0) levels; jnp ops only.  The level
-    GEMMs accumulate at ``accum_dtype`` (MXU preferred_element_type)."""
-    n0 = L.shape[-1]
-    eye = jnp.eye(n0, dtype=L.dtype)
-    d = jnp.diagonal(L)
-    A = L * (1.0 - eye) + jnp.diag(1.0 / d)
-    s = 1
-    while s < n0:
-        nb = n0 // (2 * s)
-        V = A.reshape(nb, 2 * s, nb, 2 * s)
-        idx = jnp.arange(nb)
-        blk = V[idx, :, idx, :]                     # (nb, 2s, 2s)
-        a11i = blk[:, :s, :s]
-        a22i = blk[:, s:, s:]
-        l21 = blk[:, s:, :s]
-        t = jax.lax.dot_general(l21, a11i, (((2,), (1,)), ((0,), (0,))),
-                                preferred_element_type=accum_dtype)
-        n21 = -jax.lax.dot_general(a22i, t.astype(A.dtype),
-                                   (((2,), (1,)), ((0,), (0,))),
-                                   preferred_element_type=accum_dtype)
-        blk = blk.at[:, s:, :s].set(n21.astype(A.dtype))
-        V = V.at[idx, :, idx, :].set(blk)
-        A = V.reshape(n0, n0)
-        s *= 2
-    return A
+from repro import compat
+from repro.core.blocked import tri_inv_tile
 
 
 def _tri_inv_kernel(l_ref, o_ref, *, accum_dtype):
-    o_ref[0] = _doubling_inverse(l_ref[0], accum_dtype)
+    o_ref[0] = tri_inv_tile(l_ref[0], accum_dtype)
 
 
 def _tri_inv_valid_kernel(v_ref, l_ref, o_ref, *, accum_dtype):
     """Validity-gated variant: an invalid stack entry (a block the
     structure's level schedule never touches) writes zeros instead of
     inverting — no division by its (arbitrary) diagonal."""
-    @pl.when(v_ref[0, 0] != 0)
-    def _inv():
-        o_ref[0] = _doubling_inverse(l_ref[0], accum_dtype)
+    v = v_ref[pl.program_id(0), 0]
 
-    @pl.when(v_ref[0, 0] == 0)
+    @pl.when(v != 0)
+    def _inv():
+        o_ref[0] = tri_inv_tile(l_ref[0], accum_dtype)
+
+    @pl.when(v == 0)
     def _skip():
         o_ref[0] = jnp.zeros_like(o_ref[0])
-
-
-def _out_sds(shape, dtype, like):
-    """ShapeDtypeStruct matching ``like``'s varying-manual-axes so the
-    kernel composes inside shard_map bodies."""
-    vma = getattr(jax.core.get_aval(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def tri_inv_blocks(Ls: jnp.ndarray, *, accum_dtype=jnp.float32,
@@ -107,7 +75,7 @@ def tri_inv_blocks(Ls: jnp.ndarray, *, accum_dtype=jnp.float32,
             grid=(m,),
             in_specs=[pl.BlockSpec((1, n0, n0), lambda b: (b, 0, 0))],
             out_specs=pl.BlockSpec((1, n0, n0), lambda b: (b, 0, 0)),
-            out_shape=_out_sds((m, n0, n0), Ls.dtype, Ls),
+            out_shape=compat.out_struct_like((m, n0, n0), Ls.dtype, Ls),
             interpret=interpret,
         )(Ls)
     v = jnp.asarray(valid, jnp.int32).reshape(m, 1)
@@ -115,9 +83,9 @@ def tri_inv_blocks(Ls: jnp.ndarray, *, accum_dtype=jnp.float32,
         functools.partial(_tri_inv_valid_kernel,
                           accum_dtype=jnp.dtype(accum_dtype)),
         grid=(m,),
-        in_specs=[pl.BlockSpec((1, 1), lambda b: (b, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # whole mask
                   pl.BlockSpec((1, n0, n0), lambda b: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, n0, n0), lambda b: (b, 0, 0)),
-        out_shape=_out_sds((m, n0, n0), Ls.dtype, Ls),
+        out_shape=compat.out_struct_like((m, n0, n0), Ls.dtype, Ls),
         interpret=interpret,
     )(v, Ls)

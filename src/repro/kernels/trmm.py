@@ -23,10 +23,12 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import compat
+from repro.core.precision import gemm_precision
 
 
 def _trmm_kernel(l_ref, x_ref, o_ref, acc_ref, *, nk: int, accum_dtype):
@@ -40,6 +42,8 @@ def _trmm_kernel(l_ref, x_ref, o_ref, acc_ref, *, nk: int, accum_dtype):
     @pl.when(kk <= i)          # tiles strictly above the diagonal are 0
     def _mac():
         acc_ref[...] += jnp.dot(l_ref[...], x_ref[...],
+                                precision=gemm_precision(l_ref.dtype,
+                                                         x_ref.dtype),
                                 preferred_element_type=accum_dtype)
 
     @pl.when(kk == nk - 1)
@@ -49,9 +53,9 @@ def _trmm_kernel(l_ref, x_ref, o_ref, acc_ref, *, nk: int, accum_dtype):
 
 def _trmm_masked_kernel(m_ref, l_ref, x_ref, o_ref, acc_ref, *,
                         nk: int, accum_dtype):
-    """The structure-skipping variant: one extra (1, 1) validity tile
-    per (i, kk); a zero entry skips the MXU op exactly like the
-    above-diagonal test (DESIGN.md Sec. 14)."""
+    """The structure-skipping variant: the (ni, nk) validity mask sits
+    whole in SMEM; a zero entry (i, kk) skips the MXU op exactly like
+    the above-diagonal test (DESIGN.md Sec. 14)."""
     i = pl.program_id(0)
     kk = pl.program_id(2)
 
@@ -59,21 +63,16 @@ def _trmm_masked_kernel(m_ref, l_ref, x_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((kk <= i) & (m_ref[0, 0] != 0))
+    @pl.when((kk <= i) & (m_ref[i, kk] != 0))
     def _mac():
         acc_ref[...] += jnp.dot(l_ref[...], x_ref[...],
+                                precision=gemm_precision(l_ref.dtype,
+                                                         x_ref.dtype),
                                 preferred_element_type=accum_dtype)
 
     @pl.when(kk == nk - 1)
     def _store():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _out_sds(shape, dtype, like):
-    vma = getattr(jax.core.get_aval(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def trmm(L: jnp.ndarray, X: jnp.ndarray, *, bt: int = 128, bn: int = 128,
@@ -113,7 +112,7 @@ def trmm(L: jnp.ndarray, X: jnp.ndarray, *, bt: int = 128, bn: int = 128,
             grid=grid,
             in_specs=[l_spec, x_spec],
             out_specs=o_spec,
-            out_shape=_out_sds((n, k), X.dtype, X),
+            out_shape=compat.out_struct_like((n, k), X.dtype, X),
             scratch_shapes=[pltpu.VMEM((bt, bn), accum_dtype)],
             interpret=interpret,
         )(L, X)
@@ -123,10 +122,10 @@ def trmm(L: jnp.ndarray, X: jnp.ndarray, *, bt: int = 128, bn: int = 128,
         functools.partial(_trmm_masked_kernel, nk=nk,
                           accum_dtype=accum_dtype),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i, j, kk: (i, kk)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # whole mask
                   l_spec, x_spec],
         out_specs=o_spec,
-        out_shape=_out_sds((n, k), X.dtype, X),
+        out_shape=compat.out_struct_like((n, k), X.dtype, X),
         scratch_shapes=[pltpu.VMEM((bt, bn), accum_dtype)],
         interpret=interpret,
     )(mask, L, X)

@@ -11,7 +11,8 @@ MXU-eligible flop share with and without inversion, and (b) a fallback
 for non-power-of-two blocks.
 
 Grid: (batch, column tiles).  The (n0, n0) L tile and an (n0, bn) X
-tile live in VMEM; the row loop is a lax.fori_loop over VMEM values.
+tile live in VMEM; the row loop is a lax.fori_loop that reads and
+writes one row of the refs per step.
 """
 
 from __future__ import annotations
@@ -21,44 +22,53 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro import compat
+from repro.core.precision import gemm_precision
 
 
 def _trsm_kernel(l_ref, b_ref, x_ref, *, accum_dtype):
-    L = l_ref[0]
-    B = b_ref[0]
-    n0 = L.shape[0]
+    n0 = l_ref.shape[1]
+    dt = x_ref.dtype
+    hp = gemm_precision(l_ref.dtype, dt)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n0), 1)
+    x_ref[0] = jnp.zeros(x_ref.shape[1:], dt)
 
-    def body(r, X):
-        # full-length dot; X rows >= r are still zero so they don't
-        # contribute.  One VPU row op per r — the serial baseline.
-        # The row dot and the subtraction run at accum_dtype so a
-        # low-precision recurrence does not compound rounding row by
-        # row; the carried X stays at the operand dtype.
-        d = jnp.dot(L[r], X, preferred_element_type=accum_dtype)
-        xr = (B[r].astype(accum_dtype) - d) / L[r, r].astype(accum_dtype)
-        return X.at[r].set(xr.astype(X.dtype))
+    def body(r, carry):
+        # row r of L and B read from the refs (a dynamic sublane
+        # offset, which Mosaic takes — a traced index into a loaded
+        # value is a dynamic_slice it does not); rows >= r of X are
+        # still zero, so the full-length dot only sums solved rows.
+        # One VPU row op per r — the serial baseline.  The row dot and
+        # the subtraction run at accum_dtype so a low-precision
+        # recurrence does not compound rounding row by row; the
+        # stored X stays at the operand dtype.
+        lr = l_ref[0, pl.ds(r, 1), :]                       # (1, n0)
+        d = jax.lax.dot(lr, x_ref[0], precision=hp,
+                        preferred_element_type=accum_dtype)  # (1, bn)
+        lrr = jnp.sum(jnp.where(lane == r, lr, jnp.zeros_like(lr)),
+                      axis=1, keepdims=True).astype(accum_dtype)
+        br = b_ref[0, pl.ds(r, 1), :].astype(accum_dtype)
+        x_ref[0, pl.ds(r, 1), :] = ((br - d) / lrr).astype(dt)
+        return carry
 
-    x_ref[0] = jax.lax.fori_loop(0, n0, body, jnp.zeros_like(B))
+    jax.lax.fori_loop(0, n0, body, 0)
 
 
 def _trsm_valid_kernel(v_ref, l_ref, b_ref, x_ref, *, accum_dtype):
     """Validity-gated variant: a stack entry flagged 0 skips the whole
     substitution recurrence and writes zeros (its L is never read, so
     an arbitrary/zero diagonal cannot divide)."""
-    @pl.when(v_ref[0, 0] != 0)
+    v = v_ref[pl.program_id(0), 0]
+
+    @pl.when(v != 0)
     def _solve():
         _trsm_kernel(l_ref, b_ref, x_ref, accum_dtype=accum_dtype)
 
-    @pl.when(v_ref[0, 0] == 0)
+    @pl.when(v == 0)
     def _skip():
         x_ref[0] = jnp.zeros_like(x_ref[0])
-
-
-def _out_sds(shape, dtype, like):
-    vma = getattr(jax.core.get_aval(like), "vma", None)
-    if vma:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def trsm_substitution(L: jnp.ndarray, B: jnp.ndarray, *, bn: int = 128,
@@ -90,7 +100,7 @@ def trsm_substitution(L: jnp.ndarray, B: jnp.ndarray, *, bn: int = 128,
             grid=(m, k // bn),
             in_specs=[l_spec, b_spec],
             out_specs=b_spec,
-            out_shape=_out_sds((m, n0, k), B.dtype, B),
+            out_shape=compat.out_struct_like((m, n0, k), B.dtype, B),
             interpret=interpret,
         )(L, B)
         return out[0] if squeeze else out
@@ -99,10 +109,10 @@ def trsm_substitution(L: jnp.ndarray, B: jnp.ndarray, *, bn: int = 128,
         functools.partial(_trsm_valid_kernel,
                           accum_dtype=jnp.dtype(accum_dtype)),
         grid=(m, k // bn),
-        in_specs=[pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),   # whole mask
                   l_spec, b_spec],
         out_specs=b_spec,
-        out_shape=_out_sds((m, n0, k), B.dtype, B),
+        out_shape=compat.out_struct_like((m, n0, k), B.dtype, B),
         interpret=interpret,
     )(v, L, B)
     return out[0] if squeeze else out

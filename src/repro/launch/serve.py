@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh, make_debug_mesh
 from repro.models import lm
 from repro.train import serve_step as ss
@@ -544,6 +545,7 @@ def main():
                          "occupancy, hit rate, reclaim count) after the "
                          "drain (trsm-fleet workload)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.workload == "trsm":
         return serve_trsm(args)

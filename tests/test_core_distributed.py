@@ -25,7 +25,8 @@ def run_selfcheck(name: str) -> str:
 pytestmark = pytest.mark.slow
 
 
-@pytest.mark.parametrize("check", ["order", "mm3d", "tri_inv", "rec_trsm",
+@pytest.mark.parametrize("check", ["order", "phase1_z", "mm3d", "tri_inv",
+                                   "rec_trsm",
                                    "it_inv_trsm", "doubling", "cholesky",
                                    "lu", "session", "bank", "overlap"])
 def test_selfcheck(check):

@@ -215,8 +215,9 @@ def test_async_primitives_value_equal_sync_on_degenerate_mesh(grid):
     x = np.arange(12, dtype=np.float32).reshape(3, 4)
     outs = {}
     for name, body in [("sync", sync_body), ("async", async_body)]:
+        # a gathered/permuted value is typed varying over its axes
         f = compat.shard_map(body, mesh=grid.mesh, in_specs=P(),
-                             out_specs=P())
+                             out_specs=P(("z", "x")))
         outs[name] = np.asarray(jax.jit(f)(x))
     assert np.array_equal(outs["sync"], outs["async"])
     assert np.array_equal(outs["sync"], x)      # singleton axes: no-op
@@ -240,7 +241,7 @@ def test_async_pair_prices_identically_to_sync(grid):
     costs = {}
     for name, body in [("sync", sync_body), ("async", async_body)]:
         f = compat.shard_map(body, mesh=grid.mesh, in_specs=P(),
-                             out_specs=P(None))
+                             out_specs=P("z"))
         costs[name] = comm.traced_cost(jax.jit(f), x)
     assert costs["sync"].s == costs["async"].s
     assert costs["sync"].w == costs["async"].w
@@ -248,26 +249,22 @@ def test_async_pair_prices_identically_to_sync(grid):
 
 
 def test_compat_fallback_contract():
-    """On jax builds with no async collective API (every 0.4.x) the
-    compat shims must report so, and the fallback handles must be the
-    gathered values themselves (eager issue + identity finish)."""
-    has = compat.has_async_collectives()
-    assert has == (hasattr(jax.lax, "all_gather_start")
-                   and hasattr(jax.lax, "all_gather_finish"))
-    if not has:
-        # identity-finish: finishing twice is harmless
-        from jax.sharding import PartitionSpec as P
-        g = api.make_trsm_mesh(1, 1)
+    """The installed jax has no async collective API: the compat
+    handles must be the gathered values themselves (eager issue +
+    identity finish)."""
+    # identity-finish: finishing twice is harmless
+    from jax.sharding import PartitionSpec as P
+    g = api.make_trsm_mesh(1, 1)
 
-        def body(x):
-            h = compat.async_all_gather_start(x, "y", axis=0, tiled=True)
-            return compat.async_all_gather_finish(
-                compat.async_all_gather_finish(h))
+    def body(x):
+        h = compat.async_all_gather_start(x, "y", axis=0, tiled=True)
+        return compat.async_all_gather_finish(
+            compat.async_all_gather_finish(h))
 
-        x = np.ones((2, 2), np.float32)
-        f = compat.shard_map(body, mesh=g.mesh, in_specs=P(),
-                             out_specs=P())
-        assert np.array_equal(np.asarray(jax.jit(f)(x)), x)
+    x = np.ones((2, 2), np.float32)
+    f = compat.shard_map(body, mesh=g.mesh, in_specs=P(),
+                         out_specs=P("y"))
+    assert np.array_equal(np.asarray(jax.jit(f)(x)), x)
 
 
 # ------------------------ PipelinedCost algebra ------------------------

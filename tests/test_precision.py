@@ -240,3 +240,19 @@ def test_trsm_substitution_accum_dtype():
                                    jnp.asarray(L, jnp.float32),
                                    jnp.asarray(B, jnp.float32))),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dtypes,want", [
+    ((jnp.float32,), jax.lax.Precision.HIGHEST),
+    ((jnp.float32, jnp.float32), jax.lax.Precision.HIGHEST),
+    ((jnp.bfloat16, jnp.float32), jax.lax.Precision.HIGHEST),
+    ((jnp.bfloat16,), None),
+    ((jnp.bfloat16, jnp.bfloat16), None),
+])
+def test_gemm_precision_is_highest_for_f32_only(dtypes, want):
+    """f32 GEMM operands run at HIGHEST (a TPU's default is one bf16
+    pass); bf16 operands keep the default precision."""
+    from repro.core.precision import gemm_precision
+    assert gemm_precision(*dtypes) == want
+    arrays = [jnp.zeros((2, 2), d) for d in dtypes]
+    assert gemm_precision(*arrays) == want

@@ -272,6 +272,9 @@ def test_block_inv_kernel_rejects_degenerate_blocks():
         ops.block_inv_kernel(jnp.zeros((2, 4, 8)))
     with pytest.raises(ValueError, match="stack"):
         ops.block_inv_kernel(jnp.zeros((4, 4)))
-    # n0=1 is fine (pure-jnp path), and valid blocks still invert
+    with pytest.raises(ValueError, match="power of two"):
+        ops.block_inv_kernel(jnp.ones((2, 3, 3)))
+    # n0=1 is a power of two (the kernel's reciprocal level alone), and
+    # valid blocks still invert
     out = ops.block_inv_kernel(jnp.ones((3, 1, 1)))
     np.testing.assert_allclose(np.asarray(out), np.ones((3, 1, 1)))
