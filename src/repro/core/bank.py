@@ -63,6 +63,7 @@ runtime operands, never baked-in constants.
 from __future__ import annotations
 
 import bisect
+import threading
 from typing import Callable
 
 import jax
@@ -72,6 +73,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import precision as preclib
 from repro.core import session as sessionlib
+from repro.core import spans
 from repro.core.grid import TrsmGrid
 from repro.core.session import CompiledSolverCache, SolverProgram
 
@@ -197,6 +199,10 @@ class FactorBank:
         self._chunks: list[tuple] = []
         self._size = 0
         self._stacks: tuple | None = None
+        # held from reading the stacks to dispatching on them: an
+        # updater donates the stacks it reads, so a solve must never
+        # dispatch on a tuple an update has already consumed
+        self._stacks_lock = threading.RLock()
         self._slot_ids: dict[int, object] = {}
         self._updaters: dict[tuple, object] = {}
         self.updates_dispatched = 0    # compiled scatter dispatches
@@ -453,8 +459,9 @@ class FactorBank:
         self._append_chunk(tuple(_width1(a) for a in entry), 1)
 
     def _append_chunk(self, stacks: tuple, count: int) -> None:
-        self._chunks.append(stacks)
-        self._size += count
+        with self._stacks_lock:
+            self._chunks.append(stacks)
+            self._size += count
 
     # ----------------------- live mutation (Sec. 11) -----------------------
 
@@ -535,7 +542,9 @@ class FactorBank:
         sh = NamedSharding(self.grid.mesh, spec)
         if getattr(L, "sharding", None) != sh:
             L = jax.device_put(L, sh)
-        self._stacks = prog.update(self.stacks(), self._slot_id(slot), L)
+        with self._stacks_lock:
+            self._stacks = prog.update(self.stacks(), self._slot_id(slot),
+                                       L)
         self.updates_dispatched += 1
 
     def place_factor(self, L):
@@ -558,12 +567,15 @@ class FactorBank:
         no re-stacking, no occupancy change (DESIGN.md Sec. 11).
         ``pad_to=n`` refreshes with a smaller (d, d) factor embedded as
         ``blockdiag(L, I)``, exactly as :meth:`admit`.  Returns the
-        slot."""
-        L = L if isinstance(L, jax.Array) else jnp.asarray(L)
-        pad_from = self._resolve_pad(L, pad_to)
-        self._check_square(L, 2, order=pad_from)
-        self._check_live(slot)
-        self._scatter(slot, L, "natural", pad_from=pad_from)
+        slot.  The host span ``trsm.replace`` covers the call: the
+        updater lookup, the placement and the updater's dispatch (the
+        call returns before the device has finished)."""
+        with spans.span("replace"):
+            L = L if isinstance(L, jax.Array) else jnp.asarray(L)
+            pad_from = self._resolve_pad(L, pad_to)
+            self._check_square(L, 2, order=pad_from)
+            self._check_live(slot)
+            self._scatter(slot, L, "natural", pad_from=pad_from)
         return slot
 
     def replace_run(self, start: int, Ls, *, pad_to: int | None = None
@@ -659,6 +671,19 @@ class FactorBank:
         admitted as one ``admit_stack`` IS its gather output
         (``jax.device_put`` onto the sharding it already has is
         free)."""
+        with self._stacks_lock:
+            return self._fused_stacks()
+
+    def with_stacks(self, fn, *args):
+        """``fn(stacks, *args)`` with no update of the stacks between
+        reading them and the call: how a program that reads the
+        resident stacks is dispatched while another thread may
+        replace, evict or admit (an update donates the stacks it
+        reads)."""
+        with self._stacks_lock:
+            return fn(self._fused_stacks(), *args)
+
+    def _fused_stacks(self) -> tuple:
         if self._stacks is None and not self._chunks:
             raise ValueError("empty bank: admit factors before solving")
         if self._chunks:

@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import errors as _errors
+from repro.core import spans
 from repro.core.solver import SolveServer, static_slice
 from repro.core.solver import _warn_deprecated
 
@@ -566,7 +567,22 @@ class AsyncSolveServer:
         slot, over-wide request, shape mismatch).  In fleet mode the
         request is addressed by ``(tenant, order[, tag])`` — the RHS
         row count IS the order — and a missing/stale route raises
-        ``KeyError`` here, at admission."""
+        ``KeyError`` here, at admission.
+
+        Host spans: ``trsm.submit`` over the call, with
+        ``trsm.submit.upload`` (the ingestion upload, its eager casts
+        and the checks between them) and ``trsm.submit.enqueue`` (the
+        lock, admission, the queue push and the notify) inside it."""
+        with spans.span("submit"):
+            with spans.span("submit.upload"):
+                b, key, gen, order = self._ingest(b, factor, tenant, tag)
+            with spans.span("submit.enqueue"):
+                return self._enqueue(b, key, gen, order, tenant, tag)
+
+    def _ingest(self, b, factor, tenant, tag):
+        """The request's columns on the device at the serving dtype
+        (padded to the bucket order in fleet mode), checked against
+        its route: ``(b, queue key, generation, true order)``."""
         b = jnp.asarray(b)
         if b.ndim == 1:
             b = jax.lax.expand_dims(b, (1,))
@@ -601,6 +617,9 @@ class AsyncSolveServer:
             b = jnp.asarray(b, self.solver.dtype)
             key, order = factor, int(b.shape[0])
             gen = bank.slot_generation(factor)
+        return b, key, gen, order
+
+    def _enqueue(self, b, key, gen, order, tenant, tag) -> SolveFuture:
         with self._cond:
             now = self._now()
             future = SolveFuture(tenant=tenant, tag=tag, factor=key,
@@ -667,10 +686,15 @@ class AsyncSolveServer:
         work, then finalize waves beyond the pipeline depth; with no
         work, finalize everything in flight.  Returns the number of
         requests dispatched (0 = idle).  The background loop calls
-        this; deterministic tests call it directly."""
-        with self._step_lock:
-            now = self._now()
-            with self._lock:
+        this; deterministic tests call it directly.
+
+        Host spans: ``trsm.step`` over the call, with ``trsm.pack``
+        (the stranded sweep and the packing, under the submit lock),
+        ``trsm.dispatch`` and, per finalized wave,
+        ``trsm.device_wait`` and ``trsm.resolve`` inside it."""
+        with self._step_lock, spans.span("step"):
+            with spans.span("pack"), self._lock:
+                now = self._now()
                 waves: dict = {}
                 for key, fq in list(self._queues.items()):
                     self._fail_stranded(key, fq, now)
@@ -678,13 +702,11 @@ class AsyncSolveServer:
                         wave = fq.pack()
                         if wave:
                             waves[key] = wave
-            if not waves:
-                self._finalize(all_waves=True)
-                if self._autoscaler is not None:
-                    self._autoscaler.tick()
-                return 0
-            dispatched = self._dispatch(waves)
-            self._finalize(all_waves=False)
+            dispatched = 0
+            if waves:
+                with spans.span("dispatch"):
+                    dispatched = self._dispatch(waves)
+            self._finalize(all_waves=not waves)
             if self._autoscaler is not None:
                 self._autoscaler.tick()
             return dispatched
@@ -727,12 +749,11 @@ class AsyncSolveServer:
                         X = static_slice(       # padded tail back off
                             (0, 0), (r.order, r.width))(X)
                     r.future.dispatched = now
+                    spans.record("queue", now - r.future.arrival)
                     pairs.append((r, X))
                     total += 1
         if pairs:
             self._inflight.append(pairs)
-        while len(self._inflight) > self.max_inflight:
-            self._finalize_one()
         return total
 
     def _finalize(self, *, all_waves: bool) -> None:
@@ -742,7 +763,14 @@ class AsyncSolveServer:
 
     def _finalize_one(self) -> None:
         pairs = self._inflight.popleft()
-        jax.block_until_ready([X for _, X in pairs])
+        with spans.span("device_wait"):
+            jax.block_until_ready([X for _, X in pairs])
+        with spans.span("resolve"):
+            self._resolve_wave(pairs)
+
+    def _resolve_wave(self, pairs: list) -> None:
+        """Resolve a finished wave's futures; update the counters, the
+        latency window and the per-unit wave EWMA."""
         now = self._now()
         units_seen = set()
         for r, X in pairs:

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from repro.core import errors as _errors
 from repro.core import precision as preclib
+from repro.core import spans
 from repro.core.bank import FactorBank, as_factor
 from repro.core.grid import TrsmGrid
 from repro.core.precision import PrecisionPolicy
@@ -741,7 +742,7 @@ class Solver:
             # panel and a place_rhs'd one would each trace the program
             B = jax.device_put(B, prog.rhs_sharding)
         fn = prog.solve_donating if donate else prog.solve
-        X = fn(self.bank.stacks(), B)
+        X = self.bank.with_stacks(fn, B)
         self.solves_served += self.width
         # lax.squeeze, not X[0]: the getitem spelling lowers through
         # dynamic_slice, whose index operand is a host->device upload
@@ -1030,37 +1031,47 @@ class SolveServer:
         on every wave).  Shared by :meth:`drain` (the synchronous
         caller-driven path) and the background drain loop of
         :class:`repro.core.serving.AsyncSolveServer`, which packs its
-        own waves."""
+        own waves.
+
+        Host spans: ``trsm.wave.assemble`` (the panels' concatenates,
+        filler slices and stack), ``trsm.wave.launch`` (the solve
+        program's call) and ``trsm.wave.slice`` (the result slices).
+        Each call in them enqueues a program on the device; on a busy
+        chip the runtime can hold such a call for up to a wave."""
         n, pk = self.solver.n, self.panel_k
         panels = []
-        for f in range(self.solver.width):
-            wave = waves.get(f, ())
-            if wave:
-                parts = [b for _, b in wave]
-                w = sum(b.shape[1] for b in parts)
-                if w < pk:
-                    parts.append(static_slice((0, 0), (n, pk - w))(
-                        self._filler(self.solver.dtype)))
-                panel = parts[0] if len(parts) == 1 \
-                    else jnp.concatenate(parts, axis=1)
-            else:
-                panel = self._filler(self.solver.dtype)
-            panels.append(panel)
-        X = self.solver.solve(jnp.stack(panels))
+        with spans.span("wave.assemble"):
+            for f in range(self.solver.width):
+                wave = waves.get(f, ())
+                if wave:
+                    parts = [b for _, b in wave]
+                    w = sum(b.shape[1] for b in parts)
+                    if w < pk:
+                        parts.append(static_slice((0, 0), (n, pk - w))(
+                            self._filler(self.solver.dtype)))
+                    panel = parts[0] if len(parts) == 1 \
+                        else jnp.concatenate(parts, axis=1)
+                else:
+                    panel = self._filler(self.solver.dtype)
+                panels.append(panel)
+            B = jnp.stack(panels)
+        with spans.span("wave.launch"):
+            X = self.solver.solve(B)
         self.waves_solved += 1
         out: dict = {}
-        for f, wave in waves.items():
-            off, xs = 0, []
-            for seq, b in wave:
-                j = b.shape[1]
-                # jitted static slice, not X[f, :, off:...]: both the
-                # getitem spelling and op-by-op lax.slice upload their
-                # bounds as an int32 operand per wave
-                xs.append((seq, static_slice(
-                    (f, 0, off), (f + 1, n, off + j), (0,))(X)))
-                off += j
-            out[f] = xs
-            self.requests_served += len(wave)
+        with spans.span("wave.slice"):
+            for f, wave in waves.items():
+                off, xs = 0, []
+                for seq, b in wave:
+                    j = b.shape[1]
+                    # jitted static slice, not X[f, :, off:...]: both
+                    # the getitem spelling and op-by-op lax.slice
+                    # upload their bounds as an int32 operand per wave
+                    xs.append((seq, static_slice(
+                        (f, 0, off), (f + 1, n, off + j), (0,))(X)))
+                    off += j
+                out[f] = xs
+                self.requests_served += len(wave)
         return out
 
     def warmup(self) -> "SolveServer":

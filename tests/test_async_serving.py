@@ -476,6 +476,41 @@ def test_concurrency_stress_producers_vs_churn(grid):
     assert session.TRACE_COUNTS[key] == traces
 
 
+def test_update_waits_for_a_dispatch_reading_the_stacks(grid):
+    """An update donates the stacks it reads, so it must never run
+    between a solve's read of the stacks and its dispatch: the race
+    the churn stress above can hit, made deterministic.  A replace
+    called while ``with_stacks`` holds the stacks waits for it, and
+    the program it held them for gets live buffers."""
+    n, C = 32, 2
+    Ls, rng = _factors(C, n, seed=5)
+    bank = api.FactorBank(grid, n, n0=8, capacity=C, dtype=np.float32)
+    for L in Ls:
+        bank.admit(L)
+    bank.replace(0, Ls[0])                    # the updater compiled
+    held, release = threading.Event(), threading.Event()
+    seen = []
+
+    def dispatch(stacks):
+        held.set()
+        release.wait(30)
+        seen.append(all(not a.is_deleted() for a in stacks))
+        return jax.block_until_ready(stacks)
+
+    solve = threading.Thread(target=bank.with_stacks, args=(dispatch,))
+    solve.start()
+    assert held.wait(30)
+    churn = threading.Thread(target=bank.replace, args=(1, Ls[0]))
+    churn.start()
+    churn.join(0.5)
+    assert churn.is_alive()                   # waiting on the dispatch
+    release.set()
+    solve.join(30)
+    churn.join(30)
+    assert seen == [True] and not churn.is_alive()
+    assert bank.updates_dispatched == C + 2
+
+
 # ------------------------- FairQueue unit tests -------------------------
 
 def _req(seq, tenant="t", width=1):
