@@ -66,6 +66,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import errors as _errors
 from repro.core import spans
@@ -411,6 +412,8 @@ class AsyncSolveServer:
         self.shed = 0
         self.stranded = 0
         self.waves = 0             # dispatches (step lock)
+        self.host_ingested = 0     # requests ingested on the host
+        self.unit_waves = 0        # waves of single columns (step lock)
         self._latencies: collections.deque = \
             collections.deque(maxlen=latency_window)
         self._slo_violations = 0
@@ -468,7 +471,10 @@ class AsyncSolveServer:
     def warmup(self) -> "AsyncSolveServer":
         """Compile the wave program(s) and pre-build the zero fillers,
         so the first wave — and every wave after it — runs at
-        steady-state latency with zero transfers."""
+        steady-state latency with zero transfers.  The programs of
+        waves of single columns are built at the first such wave
+        (``SolveServer._unit_programs``): a server whose requests are
+        all wide never pays for them."""
         if self.fleet is not None:
             self.fleet.warmup(self.panel_k)
             for key in self.fleet.buckets:
@@ -569,23 +575,48 @@ class AsyncSolveServer:
         row count IS the order — and a missing/stale route raises
         ``KeyError`` here, at admission.
 
+        A host ``b`` (a NumPy array or other array-like) enqueues no
+        device program: :meth:`_ingest` shapes, casts and pads it on
+        the host and sends it in one transfer.  A ``jax.Array`` ``b``
+        is shaped, cast and padded by eager device calls, each a
+        program of its own where it is not a no-op.
+
         Host spans: ``trsm.submit`` over the call, with
-        ``trsm.submit.upload`` (the ingestion upload, its eager casts
-        and the checks between them) and ``trsm.submit.enqueue`` (the
-        lock, admission, the queue push and the notify) inside it."""
+        ``trsm.submit.upload`` (the ingestion: shaping, cast, pad, the
+        checks between them and the transfer) and
+        ``trsm.submit.enqueue`` (the lock, admission, the queue push
+        and the notify) inside it."""
         with spans.span("submit"):
             with spans.span("submit.upload"):
-                b, key, gen, order = self._ingest(b, factor, tenant, tag)
+                b, key, gen, order, host = self._ingest(b, factor,
+                                                        tenant, tag)
             with spans.span("submit.enqueue"):
-                return self._enqueue(b, key, gen, order, tenant, tag)
+                return self._enqueue(b, key, gen, order, tenant, tag,
+                                     host)
 
     def _ingest(self, b, factor, tenant, tag):
         """The request's columns on the device at the serving dtype
         (padded to the bucket order in fleet mode), checked against
-        its route: ``(b, queue key, generation, true order)``."""
-        b = jnp.asarray(b)
+        its route: ``(b, queue key, generation, true order, host)``.
+
+        ``host`` is whether ``b`` came from the host.  Such a ``b`` is
+        lifted to (n, j), cast and padded in NumPy (bf16 through its
+        NumPy dtype), checked, and sent with one ``jax.device_put`` to
+        the default device, where ``jnp.asarray`` would put it: no
+        device program runs.  A ``jax.Array`` keeps the device path:
+        ``expand_dims``, the cast and the fleet pad each enqueue a
+        program when they change ``b``."""
+        host = not isinstance(b, jax.Array)
+        xp = np if host else jnp
+        b = xp.asarray(b)
+        if host:
+            # as jnp.asarray would take it (float64 -> float32 without
+            # x64), so a later cast rounds the same value
+            b = b.astype(jax.dtypes.canonicalize_dtype(b.dtype),
+                         copy=False)
         if b.ndim == 1:
-            b = jax.lax.expand_dims(b, (1,))
+            b = b.reshape(-1, 1) if host \
+                else jax.lax.expand_dims(b, (1,))
         if b.ndim != 2:
             raise ValueError(f"rhs must be (n, j), got {b.shape}")
         if b.shape[1] > self.panel_k:
@@ -593,11 +624,10 @@ class AsyncSolveServer:
                              f"{self.panel_k}")
         if self.fleet is not None:
             h = self.fleet.lookup(tenant, order=int(b.shape[0]), tag=tag)
-            bank = self.fleet.bucket(h.bucket).bank
             n_b = h.bucket[0]
-            b = jnp.asarray(b, self.fleet.solver(h.bucket).dtype)
+            b = xp.asarray(b, self.fleet.solver(h.bucket).dtype)
             if b.shape[0] < n_b:
-                b = jnp.pad(b, ((0, n_b - b.shape[0]), (0, 0)))
+                b = xp.pad(b, ((0, n_b - b.shape[0]), (0, 0)))
             key, gen, order = (h.bucket, h.slot), h.generation, h.order
         else:
             if tag is not None:
@@ -614,13 +644,17 @@ class AsyncSolveServer:
             if b.shape[0] != self.solver.n:
                 raise ValueError(f"rhs must be ({self.solver.n}, j), "
                                  f"got {b.shape}")
-            b = jnp.asarray(b, self.solver.dtype)
+            b = xp.asarray(b, self.solver.dtype)
             key, order = factor, int(b.shape[0])
             gen = bank.slot_generation(factor)
-        return b, key, gen, order
+        if host:
+            b = jax.device_put(b)
+        return b, key, gen, order, host
 
-    def _enqueue(self, b, key, gen, order, tenant, tag) -> SolveFuture:
+    def _enqueue(self, b, key, gen, order, tenant, tag,
+                 host) -> SolveFuture:
         with self._cond:
+            self.host_ingested += host
             now = self._now()
             future = SolveFuture(tenant=tenant, tag=tag, factor=key,
                                  order=order, width=int(b.shape[1]),
@@ -733,6 +767,7 @@ class AsyncSolveServer:
         total = 0
         for srv, unit in units.items():
             by_seq = {r.seq: r for wave in unit.values() for r in wave}
+            unit_waves = srv.unit_waves_solved
             try:
                 out = srv._solve_wave(
                     {slot: [(r.seq, r.b) for r in wave]
@@ -742,6 +777,7 @@ class AsyncSolveServer:
                     r.future._fail(e, now)
                 continue
             self.waves += 1
+            self.unit_waves += srv.unit_waves_solved - unit_waves
             for xs in out.values():
                 for seq, X in xs:
                     r = by_seq[seq]
@@ -802,7 +838,10 @@ class AsyncSolveServer:
     def stats(self) -> dict:
         """Serving counters + the latency distribution of the last
         ``latency_window`` completed requests: submitted / served /
-        shed / stranded / waves / pending / inflight, p50/p99/max
+        shed / stranded / waves / pending / inflight, the front door's
+        ``host_ingested`` (requests shaped and cast on the host) and
+        ``unit_waves`` (waves of single columns, assembled and split
+        by the fill-independent programs), p50/p99/max
         latency (ms), the violation count when an SLO was set, and the
         per-tenant breakdown under ``"tenants"`` (submitted / served /
         shed / deadline_shed / stranded / slo_violations each).
@@ -822,6 +861,7 @@ class AsyncSolveServer:
         return dict(
             submitted=self.submitted, served=self.served,
             shed=self.shed, stranded=self.stranded, waves=self.waves,
+            host_ingested=self.host_ingested, unit_waves=self.unit_waves,
             pending=pending, inflight=len(self._inflight),
             queue_depth=self.queue_depth,
             p50_ms=pct(0.50), p99_ms=pct(0.99),

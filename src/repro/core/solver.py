@@ -55,6 +55,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import errors as _errors
 from repro.core import precision as preclib
@@ -827,6 +828,52 @@ def static_slice(start: tuple, limit: tuple, squeeze: tuple = ()):
     return jax.jit(run)
 
 
+def unit_wave_programs(width: int, n: int, panel_k: int, sharding):
+    """The programs of a wave of single columns: ``(assemble, splits)``.
+
+    ``assemble`` takes ``width x panel_k`` (n, 1) columns, slot-major,
+    and returns the (width, n, panel_k) panel stack, on ``sharding``
+    (the solve program's input sharding) where that is one device.
+    On a mesh of several devices the stack is left on its columns'
+    device: placed by the program, every column would be copied to
+    every device, while :meth:`Solver.solve` moves only each device's
+    shard of the stack.
+
+    ``splits[m]`` returns the first ``m`` (n, 1) columns of each slot
+    of a solved stack, slot-major, for ``m`` in 1, 2, 3, 4, 6, 8, 12,
+    ... (powers of two and their halfway points) below ``panel_k``,
+    and ``panel_k`` itself; a wave takes the smallest ``m`` its fill
+    fits.  Each output is a device buffer the host makes and frees,
+    at a cost per buffer, so a split of all ``panel_k`` columns would
+    charge every wave for the full panel, and these sizes leave at
+    most a third of a split's outputs unused."""
+    place = {"out_shardings": sharding} \
+        if len(sharding.device_set) == 1 else {}
+
+    def assemble(*cols):
+        # columns stacked as rows, then one transpose: on the TPU a
+        # concatenate along axis 1 would first re-tile each (n, 1)
+        # column into a padded (n, 128) buffer
+        rows = jnp.stack([c.reshape(n) for c in cols])
+        return rows.reshape(width, panel_k, n).transpose(0, 2, 1)
+
+    def splitter(m):
+        def split(X):
+            # one transpose in row-major layout, then each column is a
+            # contiguous row: on the TPU, a column taken straight from
+            # X reads half of X's tiles
+            rows = with_layout_constraint(jnp.swapaxes(X, 1, 2),
+                                          Layout((0, 1, 2)))
+            return tuple(rows[f, c].reshape(n, 1)
+                         for f in range(width) for c in range(m))
+        return split
+
+    sizes = {s for i in range(panel_k.bit_length())
+             for s in (1 << i, 3 << i >> 1) if s < panel_k}
+    return (jax.jit(assemble, **place),
+            {m: jax.jit(splitter(m)) for m in sorted(sizes | {panel_k})})
+
+
 def _pack_wave(queue: collections.deque, panel_k: int) -> list:
     """First-fit pack one panel's worth of requests off the queue.
 
@@ -904,8 +951,10 @@ class SolveServer:
         # alone cannot catch it)
         self._req_gen: dict[int, int] = {}
         self._fillers: dict = {}     # dtype -> cached (n, panel_k) zeros
+        self._unit: dict = {}        # bank width -> unit-wave programs
         self.requests_served = 0
         self.waves_solved = 0
+        self.unit_waves_solved = 0
 
     @classmethod
     def from_spec(cls, spec: SolveSpec, factors, *, panel_k: int = 16,
@@ -1020,24 +1069,63 @@ class SolveServer:
                 jnp.zeros((self.solver.n, self.panel_k), dtype)
         return panel
 
+    def _unit_programs(self):
+        """The unit layout's ``(assemble, splits, zero column)`` at the
+        bank's current width (:func:`unit_wave_programs`), with the
+        cached zero column the free places take.  Every program is
+        compiled and run once here, at the first unit wave, so no
+        later fill compiles anything."""
+        width, n, pk = self.solver.width, self.solver.n, self.panel_k
+        progs = self._unit.get(width)
+        if progs is None:
+            sharding = self.solver.program_for(pk).rhs_sharding
+            assemble, splits = unit_wave_programs(width, n, pk, sharding)
+            zero = jnp.zeros((n, 1), self.solver.dtype)
+            stack = jax.device_put(assemble(*[zero] * (width * pk)),
+                                   sharding)    # as the solve returns it
+            for fn in splits.values():
+                fn(stack)
+            progs = self._unit[width] = (assemble, splits, zero)
+        return progs
+
     def _solve_wave(self, waves: dict) -> dict:
         """Assemble and dispatch ONE wave: ``{slot: [(seq, b), ...]}``
         -> ``{slot: [(seq, X), ...]}``, packed order preserved, X the
-        request's (n, j) column block.  Slots absent from ``waves``
-        ride along as cached zero panels; underfilled panels are
-        completed from the same cached filler (a slice of an existing
-        device array, so the steady state stays transfer-free — a
-        fresh ``jnp.pad``/getitem here would upload constants/indices
-        on every wave).  Shared by :meth:`drain` (the synchronous
-        caller-driven path) and the background drain loop of
-        :class:`repro.core.serving.AsyncSolveServer`, which packs its
-        own waves.
+        request's (n, j) column block.  Shared by :meth:`drain` (the
+        synchronous caller-driven path) and the background drain loop
+        of :class:`repro.core.serving.AsyncSolveServer`, which packs
+        its own waves.
 
-        Host spans: ``trsm.wave.assemble`` (the panels' concatenates,
-        filler slices and stack), ``trsm.wave.launch`` (the solve
-        program's call) and ``trsm.wave.slice`` (the result slices).
-        Each call in them enqueues a program on the device; on a busy
-        chip the runtime can hold such a call for up to a wave."""
+        When every request of the wave, in every slot, is one column
+        wide, the wave takes the unit path: one cached program builds
+        the panel stack from the requests' columns and a cached zero
+        column in every free place, the solve runs, and one cached
+        program splits off each slot's first ``m`` columns, ``m`` the
+        smallest of a few fixed sizes the fill fits; the requests take
+        theirs in packed order.  A wave then enqueues
+        three device programs whatever its fill, and after the first
+        unit wave no fill compiles anything new
+        (:meth:`_unit_programs`).
+
+        Every other layout (wider or mixed widths) concatenates each
+        slot's requests with a slice of the cached zero filler, rides
+        absent slots along as the filler itself, stacks, solves, and
+        slices each request's block out with its own
+        :func:`static_slice`: each concatenate, filler slice and
+        result slice is a device program of its own, compiled per fill
+        and offset.  All of them come from device arrays already held
+        (a fresh ``jnp.pad``/getitem here would upload constants or
+        indices on every wave), so the steady state stays
+        transfer-free on either path.
+
+        Host spans: ``trsm.wave.assemble`` (the panel stack's
+        programs), ``trsm.wave.launch`` (the solve program's call) and
+        ``trsm.wave.slice`` (the result programs).  On a busy chip the
+        runtime can hold a call that enqueues a program for up to a
+        wave."""
+        if all(b.shape[1] == 1 for wave in waves.values()
+               for _, b in wave):
+            return self._solve_unit_wave(waves)
         n, pk = self.solver.n, self.panel_k
         panels = []
         with spans.span("wave.assemble"):
@@ -1072,6 +1160,30 @@ class SolveServer:
                     off += j
                 out[f] = xs
                 self.requests_served += len(wave)
+        return out
+
+    def _solve_unit_wave(self, waves: dict) -> dict:
+        """:meth:`_solve_wave` for a wave of single columns only."""
+        assemble, splits, zero = self._unit_programs()
+        pk = self.panel_k
+        fill = max(len(wave) for wave in waves.values())
+        m = min(s for s in splits if s >= fill)
+        with spans.span("wave.assemble"):
+            cols = [zero] * (self.solver.width * pk)
+            for f, wave in waves.items():
+                for c, (_, b) in enumerate(wave):
+                    cols[f * pk + c] = b
+            B = assemble(*cols)
+        with spans.span("wave.launch"):
+            X = self.solver.solve(B)
+        self.waves_solved += 1
+        self.unit_waves_solved += 1
+        with spans.span("wave.slice"):
+            xs = splits[m](X)
+            out = {f: [(seq, xs[f * m + c])
+                       for c, (seq, _) in enumerate(wave)]
+                   for f, wave in waves.items()}
+            self.requests_served += sum(len(w) for w in waves.values())
         return out
 
     def warmup(self) -> "SolveServer":

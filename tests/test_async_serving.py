@@ -2,7 +2,9 @@
 queue bounds + typed shedding, FIFO-per-tenant ordering and future
 resolution order, weighted fair packing, evict-under-flight stranding
 through the future (plain AND fleet), the zero-retrace/zero-transfer
-steady state, and a producer-thread stress with capacity churn.
+steady state, the single-column path (host ingest, waves whose programs
+do not depend on the fill), and a producer-thread stress with capacity
+churn.
 
 Everything except the lifecycle/stress tests runs with NO background
 thread and NO wall-clock: the server gets the ``fake_clock`` fixture
@@ -320,26 +322,54 @@ def test_fleet_async_mixed_orders_slice_back(grid, fake_clock,
         assert f.exception() is None
 
 
+def test_fleet_async_host_vector_padded_on_host_and_sliced_back(
+        grid, fake_clock, drain_driver):
+    """A host vector shorter than its bucket is cast and zero-padded
+    to the bucket order on the host, uploaded once, and its solution
+    comes back at its true order."""
+    # a dispatch priced high enough that both orders share one bucket
+    plan = api.plan_fleet({48: 1, 64: 1}, grid=grid, dispatch_s=1.0)
+    assert [b.n for b in plan.buckets] == [64]
+    fleet = api.SolverFleet(grid, plan)
+    rng = np.random.default_rng(5)
+    Ls = {}
+    for t, order in (("alice", 48), ("bob", 64)):
+        Ls[t] = (np.tril(rng.standard_normal((order, order)))
+                 + order * np.eye(order)).astype(np.float32)
+        fleet.admit(Ls[t], tenant=t)
+    srv = api.AsyncSolveServer(fleet, panel_k=4,
+                               clock=fake_clock).warmup()
+    b = rng.standard_normal(48)               # float64, as callers hold
+    fut = srv.submit(b, tenant="alice")
+    (fq,) = [q for q in srv._queues.values() if len(q)]
+    staged = fq._reqs[0].b
+    assert isinstance(staged, jax.Array) and staged.shape == (64, 1)
+    assert staged.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(staged)[48:], 0)
+    np.testing.assert_array_equal(np.asarray(staged)[:48, 0],
+                                  b.astype(np.float32))
+    drain_driver(srv).run_until_idle()
+    X = fut.result(timeout=0)
+    assert X.shape == (48, 1)
+    assert _rel(Ls["alice"], X[:, 0], b) < 1e-4
+    assert srv.stats()["host_ingested"] == 1
+
+
 # ------------------------- the steady state -------------------------
 
-def test_async_steady_state_zero_retrace_zero_transfer(grid,
-                                                       fake_clock,
-                                                       drain_driver):
-    """After warmup + one priming wave, waves pack and dispatch with
-    ZERO retraces and ZERO host->device transfers — submits of
-    device-resident RHS included (the acceptance invariant the open
-    Poisson bench leans on)."""
+def _zero_retrace_zero_transfer(grid, fake_clock, drain_driver, cols):
     srv, Ls, _, rng = _server(grid, M=2, panel_k=4, max_inflight=1,
                               clock=fake_clock)
     key = srv.solver.program_for(srv.panel_k).key
     import jax.numpy as jnp
-    bs = [jnp.asarray(rng.standard_normal((32, 2)).astype(np.float32))
-          for _ in range(8)]
+    bs = [jnp.asarray(rng.standard_normal((32, cols))
+                      .astype(np.float32)) for _ in range(8)]
     jax.block_until_ready(bs)
     drv = drain_driver(srv)
     srv.submit(bs[0], factor=0)               # priming wave
     drv.run_until_idle()
     before = session.TRACE_COUNTS[key]
+    st0 = srv.stats()
     with jax.transfer_guard("disallow"):
         futs = [srv.submit(b, factor=i % 2)
                 for i, b in enumerate(bs)]
@@ -348,6 +378,103 @@ def test_async_steady_state_zero_retrace_zero_transfer(grid,
     for i, (b, f) in enumerate(zip(bs, futs)):
         assert _rel(Ls[i % 2], f.result(timeout=0), np.asarray(b)) \
             < 1e-4
+    st = srv.stats()
+    return st["waves"] - st0["waves"], st["unit_waves"] - st0["unit_waves"]
+
+
+def test_async_steady_state_zero_retrace_zero_transfer(grid,
+                                                       fake_clock,
+                                                       drain_driver):
+    """After warmup + one priming wave, waves pack and dispatch with
+    ZERO retraces and ZERO host->device transfers — submits of
+    device-resident RHS included (the acceptance invariant the open
+    Poisson bench leans on)."""
+    waves, unit = _zero_retrace_zero_transfer(grid, fake_clock,
+                                              drain_driver, cols=2)
+    assert waves >= 1 and unit == 0
+
+
+def test_async_steady_state_zero_retrace_zero_transfer_unit_width(
+        grid, fake_clock, drain_driver):
+    """The same invariant for device (n, 1) submits, which take the
+    unit-wave programs (assemble, split) instead of the per-request
+    slices."""
+    waves, unit = _zero_retrace_zero_transfer(grid, fake_clock,
+                                              drain_driver, cols=1)
+    assert waves >= 1 and unit == waves
+
+
+# ----------------------- the unit-column path -----------------------
+
+def _counting_compiles():
+    seen = []
+
+    def listen(event, *_a, **_k):
+        if "backend_compile" in event:
+            seen.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    return seen, lambda: \
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.mark.parametrize("M", [1, 2])
+def test_unit_waves_compile_nothing_at_any_fill(grid, fake_clock,
+                                                drain_driver, M):
+    """After warmup and one priming wave, host-vector waves of every
+    fill 1..panel_k compile nothing, and every answer equals, bit for
+    bit, the same column sent as a device (n, 1) array through the
+    general path (a wave mixed with a width-2 request)."""
+    import jax.numpy as jnp
+    pk = 8
+    srv, Ls, _, rng = _server(grid, M=M, panel_k=pk, clock=fake_clock)
+    drv = drain_driver(srv)
+    srv.submit(rng.standard_normal(32).astype(np.float32))
+    drv.run_until_idle()                       # priming wave
+    sent, futs = [], []
+    seen, stop = _counting_compiles()
+    try:
+        for fill in range(1, pk + 1):
+            for c in range(fill * M):
+                b = rng.standard_normal(32).astype(np.float32)
+                sent.append((c % M, b))
+                futs.append(srv.submit(b, factor=c % M))
+            assert drv.step() == fill * M      # one wave, this fill
+            drv.run_until_idle()
+    finally:
+        stop()
+    assert seen == []
+    pair = jnp.asarray(rng.standard_normal((32, 2)).astype(np.float32))
+    for (f, b), fut in zip(sent, futs):
+        ref = srv.submit(jnp.asarray(b[:, None]), factor=f)
+        srv.submit(pair, factor=f)
+        drv.run_until_idle()
+        np.testing.assert_array_equal(np.asarray(fut.result(timeout=0)),
+                                      np.asarray(ref.result(timeout=0)))
+        assert _rel(Ls[f], fut.result(timeout=0)[:, 0], b) < 1e-4
+
+
+@pytest.mark.parametrize("host", [True, False])
+def test_stats_count_host_ingest_and_unit_waves(grid, fake_clock,
+                                                drain_driver, host):
+    """Host single columns: every request host-ingested, every wave a
+    unit wave.  Device width-2 blocks: neither."""
+    import jax.numpy as jnp
+    srv, _, _, rng = _server(grid, clock=fake_clock)
+    for i in range(10):
+        if host:
+            b = rng.standard_normal(32).astype(np.float32)
+        else:
+            b = jnp.asarray(rng.standard_normal((32, 2))
+                            .astype(np.float32))
+        srv.submit(b, factor=i % 2)
+    drain_driver(srv).run_until_idle()
+    st = srv.stats()
+    assert st["served"] == 10 and st["waves"] > 1
+    if host:
+        assert st["host_ingested"] == 10 and st["unit_waves"] == \
+            st["waves"]
+    else:
+        assert st["host_ingested"] == 0 and st["unit_waves"] == 0
 
 
 # ----------------------- lifecycle + the thread -----------------------

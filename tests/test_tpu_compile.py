@@ -103,6 +103,29 @@ def test_served_programs_compile_for_v5e(topo, p1, p2, n):
     _fits(prog.solve_donating.lower(factor, rhs).compile())
 
 
+def test_unit_wave_programs_compile_for_v5e(topo):
+    """The front door's unit-wave programs at ``vec``'s size (n =
+    32768, panel 256): the assemble needs no re-tiled copy of each
+    column (4 GiB of scratch as a concatenate along the columns), and
+    the split's outputs are compact (n, 1) columns."""
+    from repro.core.solver import unit_wave_programs
+    grid = _grid(topo, 1, 1)
+    n, pk = N_CHIP, 256
+    sharding = NamedSharding(grid.mesh, P())
+    assemble, splits = unit_wave_programs(1, n, pk, sharding)
+    assert sorted(splits) == [1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64,
+                              96, 128, 192, 256]
+    col = _sds(grid, (n, 1), jnp.float32, P())
+    panel = n * pk * 4
+    m = assemble.lower(*[col] * pk).compile().memory_analysis()
+    assert m.output_size_in_bytes == panel
+    assert m.temp_size_in_bytes < panel, m.temp_size_in_bytes
+    stack = _sds(grid, (1, n, pk), jnp.float32, P())
+    m = splits[pk].lower(stack).compile().memory_analysis()
+    assert m.output_size_in_bytes < 2 * panel, m.output_size_in_bytes
+    assert m.temp_size_in_bytes < panel, m.temp_size_in_bytes
+
+
 def _kernel_call(name, n0):
     from repro.kernels import trmm, tri_inv_block, trsm_block
     f32 = jnp.float32
