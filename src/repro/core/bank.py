@@ -58,6 +58,12 @@ Programs come from the same :class:`CompiledSolverCache`; the bank
 width M (and map mode) join the cache key, so two same-width banks of
 the same configuration share one compiled program and the factors are
 runtime operands, never baked-in constants.
+
+Admission runs under the host span ``trsm.admit`` (``core/spans.py``),
+which holds ``trsm.admit.ingest`` (the gather or the casts) and
+``trsm.admit.phase1`` (phase 1's dispatch to the entry being ready)
+where they are separate programs; a capacity bank's admission is one
+updater program, under ``trsm.admit`` alone.
 """
 
 from __future__ import annotations
@@ -354,19 +360,24 @@ class FactorBank:
         self._check_square(L, 2, order=pad_from)
         if self.capacity is not None:
             return self._admit_slot(L, "natural", pad_from=pad_from)
-        preps = sessionlib._factor_preps(self.grid, self.lower,
-                                         self.transpose, self.policy,
-                                         structure=self.structure,
-                                         n0=self.n0)
-        if not isinstance(L, jax.Array):
-            # one upload for every prep (a refining policy has two)
-            L = jax.device_put(L, NamedSharding(self.grid.mesh, P()))
-        parts = tuple(p(L) for p in preps)
-        del L
-        # the upload is freed once the preps finish: wait for that
-        # before phase 1 allocates its scratch next to the copies
-        jax.block_until_ready(parts)
-        self._append(self._entry(parts))
+        with spans.span("admit"):
+            preps = sessionlib._factor_preps(self.grid, self.lower,
+                                             self.transpose, self.policy,
+                                             structure=self.structure,
+                                             n0=self.n0)
+            with spans.span("admit.ingest"):
+                if not isinstance(L, jax.Array):
+                    # one upload for every prep (a refining policy has
+                    # two)
+                    L = jax.device_put(L, NamedSharding(self.grid.mesh,
+                                                        P()))
+                parts = tuple(p(L) for p in preps)
+                del L
+                # the upload is freed once the preps finish: wait for
+                # that before phase 1 allocates its scratch next to
+                # the copies
+                jax.block_until_ready(parts)
+            self._append(self._phase1_ready(parts))
         return self.size - 1
 
     def admit_stack(self, Ls):
@@ -389,28 +400,34 @@ class FactorBank:
             if self._size == 0 and M == self.capacity:
                 # full-width fast path: the stacked gather output IS
                 # the resident stack — no per-slot scatters at all
-                preps = sessionlib._factor_preps(
-                    self.grid, self.lower, self.transpose, self.policy,
-                    stacked=True, structure=self.structure, n0=self.n0)
-                entry = self._entry(tuple(p(Ls) for p in preps),
-                                    stacked=True)
-                self._stacks = tuple(
-                    jax.device_put(a, NamedSharding(self.grid.mesh,
-                                                    P(None, *spec)))
-                    for a, spec in zip(entry, self._role_specs()))
+                with spans.span("admit"):
+                    preps = sessionlib._factor_preps(
+                        self.grid, self.lower, self.transpose,
+                        self.policy, stacked=True,
+                        structure=self.structure, n0=self.n0)
+                    with spans.span("admit.ingest"):
+                        parts = tuple(p(Ls) for p in preps)
+                    entry = self._phase1_ready(parts, stacked=True)
+                    self._stacks = tuple(
+                        jax.device_put(a, NamedSharding(self.grid.mesh,
+                                                        P(None, *spec)))
+                        for a, spec in zip(entry, self._role_specs()))
                 self._live = [True] * M
                 self._free = []
                 self._size = M
                 return list(range(M))
             return [self.admit(Ls[j]) for j in range(M)]
-        preps = sessionlib._factor_preps(self.grid, self.lower,
-                                         self.transpose, self.policy,
-                                         stacked=True,
-                                         structure=self.structure,
-                                         n0=self.n0)
-        stacks = self._entry(tuple(p(Ls) for p in preps), stacked=True)
-        first = self.size
-        self._append_chunk(stacks, Ls.shape[0])
+        with spans.span("admit"):
+            preps = sessionlib._factor_preps(self.grid, self.lower,
+                                             self.transpose, self.policy,
+                                             stacked=True,
+                                             structure=self.structure,
+                                             n0=self.n0)
+            with spans.span("admit.ingest"):
+                parts = tuple(p(Ls) for p in preps)
+            stacks = self._phase1_ready(parts, stacked=True)
+            first = self.size
+            self._append_chunk(stacks, Ls.shape[0])
         return range(first, self.size)
 
     def admit_cyclic(self, L_cyc) -> int:
@@ -443,15 +460,30 @@ class FactorBank:
         if self.capacity is not None:
             return self._admit_slot(L_cyc, "cyclic")
         sharding = NamedSharding(self.grid.mesh, self.grid.spec_L())
-        dts = (self.policy.storage_dtype,)
-        if self.policy.refines:
-            dts += (self.policy.residual_dtype,)
-        # copies: _append donates them, never the caller's buffer
-        parts = tuple(jax.device_put(jnp.asarray(L_cyc, dt), sharding,
-                                     may_alias=False)
-                      for dt in dts)
-        self._append(self._entry(parts))
+
+        def cast(dt):
+            # a copy: _append donates it, never the caller's buffer
+            with spans.span("admit.ingest"):
+                return jax.device_put(jnp.asarray(L_cyc, dt), sharding,
+                                      may_alias=False)
+        with spans.span("admit"):
+            entry = self._phase1_ready((cast(self.policy.storage_dtype),))
+            if self.policy.refines:
+                # made once phase 1 has finished, so it never sits next
+                # to phase 1's scratch (4 GiB each per chip at n =
+                # 65536 on mesh (2, 1))
+                entry += (cast(self.policy.residual_dtype),)
+            self._append(entry)
         return self.size - 1
+
+    def _phase1_ready(self, parts: tuple, stacked: bool = False) -> tuple:
+        """:meth:`_entry` of the ingested ``parts``, waited for: the
+        ``trsm.admit.phase1`` span, dispatch to the stacks being
+        ready."""
+        with spans.span("admit.phase1"):
+            entry = self._entry(parts, stacked)
+            jax.block_until_ready(entry)
+        return entry
 
     def _append(self, entry: tuple) -> None:
         """Admit one factor: a chunk of width 1.  The entry's arrays
@@ -480,7 +512,8 @@ class FactorBank:
         leaking it."""
         slot = self._alloc_slot()
         try:
-            self._scatter(slot, L, ingest, pad_from=pad_from)
+            with spans.span("admit"):
+                self._scatter(slot, L, ingest, pad_from=pad_from)
         except BaseException:
             bisect.insort(self._free, slot)
             raise

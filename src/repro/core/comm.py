@@ -75,6 +75,12 @@ class CostTrace:
     def f(self) -> float:
         return sum(r.f * r.mult for r in self.records)
 
+    @property
+    def count(self) -> float:
+        """Collectives issued over more than one device (a size-1 axis
+        moves nothing)."""
+        return sum(r.mult for r in self.records if r.p > 1)
+
     def by_op(self) -> dict:
         out: dict[str, dict] = {}
         for r in self.records:

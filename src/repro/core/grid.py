@@ -170,6 +170,53 @@ def cyclic_matrix_device(A, p_row: int, p_col: int, *,
     return A
 
 
+# ------------------ diagonal blocks from cyclic pieces ------------------
+#
+# Phase 1 gathers the cyclic pieces of whole diagonal blocks, inverts
+# the blocks in natural order, and sends pieces back.  Spelled as a
+# reshape and transpose, the interleave g = l*p + r puts the size-p
+# axis minor to l, and the TPU's (8, 128) tiling pads that axis to 8
+# sublanes or 128 lanes: up to 64x the block's bytes.  These helpers
+# instead lay whole pieces side by side in cyclic storage order and
+# undo the storage order with the static gathers of
+# cyclic_matrix_device, so every intermediate keeps the pieces' own
+# (large) axes minor.
+
+def assemble_blocks(pieces, p1: int, p2: int):
+    """(p, m, a, b) cyclic pieces, one per device of the (x, y, z) mesh
+    in x-major order -> (m, a*p1, b*p1*p2) whole blocks in natural
+    order: rows l*p1 + x, columns c*p1*p2 + z*p1 + y."""
+    p, m, a, b = pieces.shape
+    if p == 1:
+        return pieces.reshape(m, a, b)
+    R = pieces.reshape(p1, p1, p2, m, a, b)        # [x, y, z, i, l, c]
+    S = jnp.concatenate([                          # rows x-major, columns
+        jnp.concatenate([R[x, y, z] for z in range(p2)   # (z, y)-major
+                         for y in range(p1)], axis=-1)
+        for x in range(p1)], axis=-2)
+    return cyclic_matrix_device(S, p1, p1 * p2, inverse=True)
+
+
+def block_pieces(blocks, p_row: int, p_col: int):
+    """(m, s, t) natural-order blocks -> (p_row, p_col, m, s/p_row,
+    t/p_col): piece [r, c] holds the rows = r (mod p_row) and the
+    columns = c (mod p_col) of every block."""
+    m, s, t = blocks.shape
+    T = cyclic_matrix_device(blocks, p_row, p_col)
+    T = T.reshape(m, p_row, s // p_row, p_col, t // p_col)
+    return jnp.transpose(T, (1, 3, 0, 2, 4))
+
+
+def block_piece(blocks, row_off, col_off, p_row: int, p_col: int):
+    """One piece of :func:`block_pieces`, at offsets that may be
+    traced: (m, s/p_row, t/p_col)."""
+    _, s, t = blocks.shape
+    a, b = s // p_row, t // p_col
+    T = cyclic_matrix_device(blocks, p_row, p_col)
+    T = jax.lax.dynamic_slice_in_dim(T, row_off * a, a, axis=1)
+    return jax.lax.dynamic_slice_in_dim(T, col_off * b, b, axis=2)
+
+
 def shard(grid: TrsmGrid, arr, spec):
     return jax.device_put(arr, NamedSharding(grid.mesh, spec))
 

@@ -45,6 +45,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 
 from repro.core import blocked, comm
+from repro.core import grid as gridlib
 from repro.core import tri_inv as ti
 from repro.core.grid import TrsmGrid, check_divisibility
 from repro.core.precision import gemm_precision
@@ -52,25 +53,10 @@ from repro.core.precision import gemm_precision
 MESH_AXES = ("x", "y", "z")
 
 
-def _assemble_blocks(Dg: jnp.ndarray, p1: int, p2: int) -> jnp.ndarray:
-    """(p, m, a, b) gathered pieces (flattened (x,y,z)-major leading axis)
-    -> (m, n0, n0) full blocks.  Rows interleave as g = l*p1 + x, columns
-    as c*p1*p2 + z*p1 + y."""
-    p, m, a, b = Dg.shape
-    R = Dg.reshape(p1, p1, p2, m, a, b)            # (x, y, z, i, l, c)
-    R = jnp.transpose(R, (3, 4, 0, 5, 2, 1))       # (i, l, x, c, z, y)
-    return R.reshape(m, a * p1, b * p2 * p1)
-
-
 def _piece_for(binv: jnp.ndarray, row_off, col_off, p1: int) -> jnp.ndarray:
     """Select the cyclic piece binv[:, row_off::p1, col_off::p1]
     with traced offsets."""
-    m, n0, _ = binv.shape
-    a = n0 // p1
-    R = binv.reshape(m, a, p1, a, p1)
-    R = jnp.moveaxis(R, (2, 4), (0, 1))            # (roff, coff, m, a, a)
-    R = jax.lax.dynamic_index_in_dim(R, row_off, axis=0, keepdims=False)
-    return jax.lax.dynamic_index_in_dim(R, col_off, axis=0, keepdims=False)
+    return gridlib.block_piece(binv, row_off, col_off, p1, p1)
 
 
 def _pieces_all_dests(binv: jnp.ndarray, p1: int, p2: int) -> jnp.ndarray:
@@ -78,8 +64,8 @@ def _pieces_all_dests(binv: jnp.ndarray, p1: int, p2: int) -> jnp.ndarray:
     (rows ≡ yd, cols ≡ xd) of each local block: -> (p, mb, a, a)."""
     mb, n0, _ = binv.shape
     a = n0 // p1
-    R = binv.reshape(mb, a, p1, a, p1)             # (i, l, roff, c, coff)
-    R = jnp.transpose(R, (4, 2, 0, 1, 3))          # (coff=xd, roff=yd, i, l, c)
+    R = gridlib.block_pieces(binv, p1, p1)         # (roff, coff, i, l, c)
+    R = jnp.swapaxes(R, 0, 1)                      # (coff=xd, roff=yd, ...)
     R = jnp.broadcast_to(R[:, :, None], (p1, p1, p2, mb, a, a))
     return R.reshape(p1 * p1 * p2, mb, a, a)
 
@@ -117,7 +103,7 @@ def _invert_diag_blocks(Lloc, *, n, n0, p1, p2, block_inv, mode,
         Dr = comm.all_to_all(D, MESH_AXES, split_axis=0, concat_axis=0,
                              tiled=True)            # (p*mb, a, b)
         Dr = Dr.reshape(p, mb, a, b)
-        blocks = _assemble_blocks(Dr, p1, p2)          # (mb, n0, n0)
+        blocks = ti._assemble_blocks(Dr, p1, p2)       # (mb, n0, n0)
         binv = block_inv(blocks)
         S = _pieces_all_dests(binv, p1, p2)            # (p, mb, a, a)
         Dt = comm.all_to_all(S.reshape(p * mb, a, a), MESH_AXES,
@@ -149,7 +135,7 @@ def _invert_diag_blocks(Lloc, *, n, n0, p1, p2, block_inv, mode,
         return Dd                                      # (m, a, a)
     elif mode == "allgather":
         Dg = comm.all_gather(D, MESH_AXES, axis=0, tiled=False)
-        blocks = _assemble_blocks(Dg, p1, p2)          # (m, n0, n0)
+        blocks = ti._assemble_blocks(Dg, p1, p2)       # (m, n0, n0)
         binv = block_inv(blocks)
         return _piece_for(binv, yi, xi, p1)            # (m, a, a)
     raise ValueError(mode)

@@ -445,13 +445,83 @@ def check_overlap() -> int:
     return fails
 
 
+# Phase 1's data movement spelled as reshapes and transposes (a
+# size-p1/p2 axis minor-most, which the TPU's tiling pads): the
+# reference that the gathers of repro.core.grid must match bit for bit.
+def _legacy_assemble_blocks(Dg, p1, p2):
+    p, m, a, b = Dg.shape
+    R = Dg.reshape(p1, p1, p2, m, a, b)            # (x, y, z, i, l, c)
+    R = jnp.transpose(R, (3, 4, 0, 5, 2, 1))       # (i, l, x, c, z, y)
+    return R.reshape(m, a * p1, b * p2 * p1)
+
+
+def _legacy_piece_for(binv, row_off, col_off, p1):
+    m, n0, _ = binv.shape
+    a = n0 // p1
+    R = jnp.moveaxis(binv.reshape(m, a, p1, a, p1), (2, 4), (0, 1))
+    R = jax.lax.dynamic_index_in_dim(R, row_off, axis=0, keepdims=False)
+    return jax.lax.dynamic_index_in_dim(R, col_off, axis=0, keepdims=False)
+
+
+def _legacy_pieces_all_dests(binv, p1, p2):
+    mb, n0, _ = binv.shape
+    a = n0 // p1
+    R = binv.reshape(mb, a, p1, a, p1)             # (i, l, roff, c, coff)
+    R = jnp.transpose(R, (4, 2, 0, 1, 3))
+    R = jnp.broadcast_to(R[:, :, None], (p1, p1, p2, mb, a, a))
+    return R.reshape(p1 * p1 * p2, mb, a, a)
+
+
+def _legacy_cyclic_piece(blocks, x, y, z, p1, p2):
+    m, s, _ = blocks.shape
+    a, b = s // p1, s // (p1 * p2)
+    R = blocks.reshape(m, a, p1, b, p2, p1)        # [i, l, x, c', z, y]
+    R = jnp.moveaxis(R, (2, 4, 5), (0, 1, 2))      # [x, z, y, i, l, c']
+    R = jax.lax.dynamic_index_in_dim(R, x, axis=0, keepdims=False)
+    R = jax.lax.dynamic_index_in_dim(R, z, axis=0, keepdims=False)
+    return jax.lax.dynamic_index_in_dim(R, y, axis=0, keepdims=False)
+
+
+def _legacy_pieces_for_all(blocks, p1, p2):
+    m, s, _ = blocks.shape
+    a, b = s // p1, s // (p1 * p2)
+    R = blocks.reshape(m, a, p1, b, p2, p1)        # [i, l, x, c', z, y]
+    R = jnp.transpose(R, (2, 5, 4, 0, 1, 3))       # [x, y, z, i, l, c']
+    return R.reshape(p1 * p1 * p2, m, a, b)
+
+
+def _legacy_phase1():
+    """Context: phase 1 built from the legacy data movement."""
+    import contextlib
+    from repro.core import inv_trsm, tri_inv
+
+    @contextlib.contextmanager
+    def swapped():
+        swaps = [(inv_trsm, "_piece_for", _legacy_piece_for),
+                 (inv_trsm, "_pieces_all_dests", _legacy_pieces_all_dests),
+                 (tri_inv, "_assemble_blocks", _legacy_assemble_blocks),
+                 (tri_inv, "_cyclic_piece", _legacy_cyclic_piece),
+                 (tri_inv, "_pieces_for_all", _legacy_pieces_for_all)]
+        saved = [(mod, name, getattr(mod, name)) for mod, name, _ in swaps]
+        for mod, name, fn in swaps:
+            setattr(mod, name, fn)
+        try:
+            yield
+        finally:
+            for mod, name, fn in saved:
+                setattr(mod, name, fn)
+    return swapped()
+
+
 def check_phase1_z() -> int:
     """The phase-1 program runs with the vma check off because its
     out_specs claim Dt is replicated over z (see
     ``inv_trsm.it_inv_phase1_sharded``).  Assert that claim: on every
-    mesh a four-chip host allows, for every phase-1 mode, the z-replica
-    shards of Dt are BIT-equal — and the assembled Dt is the cyclic
-    storage of the inverted diagonal blocks."""
+    mesh a four-chip host allows, and on (2, 2), for every phase-1
+    mode, the z-replica shards of Dt are BIT-equal, Dt is bit-equal to
+    the Dt of the legacy data movement (``_legacy_phase1``), and the
+    assembled Dt is the cyclic storage of the inverted diagonal
+    blocks."""
     from jax.sharding import NamedSharding
     from repro.core import grid as gridlib
     from repro.core import inv_trsm, tri_inv
@@ -460,11 +530,13 @@ def check_phase1_z() -> int:
     fails = 0
     n = 64
     L = _random_tril(4, n)
-    for (p1, p2) in [(1, 1), (1, 2), (1, 4), (2, 1)]:
+    for (p1, p2) in [(1, 1), (1, 2), (1, 4), (2, 1), (2, 2)]:
         grid = gridlib.make_trsm_mesh(p1, p2)
         Lc = jax.device_put(gridlib.to_cyclic_matrix(L, p1, p1 * p2),
                             NamedSharding(grid.mesh, grid.spec_L()))
         for n0 in (8, 16, 32):
+            if n0 % (p1 * p2):
+                continue
             m = n // n0
             s0 = min(tri_inv.pick_s0(n, p1, p2), n0)
             modes = ["allgather"]
@@ -479,17 +551,90 @@ def check_phase1_z() -> int:
             for mode in modes:
                 Dt = jax.jit(inv_trsm.it_inv_phase1_sharded(
                     grid, n, n0, mode=mode))(Lc)
+                with _legacy_phase1():
+                    old = jax.jit(inv_trsm.it_inv_phase1_sharded(
+                        grid, n, n0, mode=mode))(Lc)
                 replicas: dict = {}
                 for sh in Dt.addressable_shards:
                     replicas.setdefault(str(sh.index), []).append(
                         np.asarray(sh.data).tobytes())
                 bit = all(len(set(r)) == 1 for r in replicas.values())
+                same = np.asarray(Dt).tobytes() == np.asarray(old).tobytes()
                 err = np.abs(np.asarray(Dt) - want).max()
-                ok = bit and err < 1e-10
+                ok = bit and same and err < 1e-10
                 print(f"phase1_z p1={p1} p2={p2} n0={n0} mode={mode}: "
-                      f"z-replicas bit-equal={bit} err={err:.2e} "
-                      f"{'OK' if ok else 'FAIL'}")
+                      f"z-replicas bit-equal={bit} legacy bit-equal={same} "
+                      f"err={err:.2e} {'OK' if ok else 'FAIL'}")
                 fails += 0 if ok else 1
+    return fails
+
+
+def _dominant_tril(seed, n):
+    """HPL-MxP's recipe in small: strictly lower part uniform in
+    [-1/2, 1/2), n on the diagonal (float32)."""
+    rng = np.random.default_rng(seed)
+    L = np.tril(rng.uniform(-0.5, 0.5, (n, n)), -1) + n * np.eye(n)
+    return L.astype(np.float32)
+
+
+def _backward_error(L, X, B) -> float:
+    """Normwise float64 backward error of X to L X = B."""
+    L, X, B = (np.asarray(a, np.float64) for a in (L, X, B))
+    return float(np.abs(L @ X - B).sum(1).max()
+                 / (np.abs(L).sum(1).max() * np.abs(X).sum(1).max()
+                    + np.abs(B).sum(1).max()))
+
+
+def check_cyclic_serve() -> int:
+    """The four-chip HPL-MxP deployment in small: a seeded diagonally
+    dominant factor of order 512 made in cyclic storage on mesh (2, 1),
+    admitted by ``FactorBank.admit_cyclic`` at the plan the front door
+    picks, served by ``Solver`` and by ``AsyncSolveServer`` under
+    ``bf16_refine`` and ``fp32``.  Each answer is held to the float64
+    triangular solve (LAPACK) and to a backward-error limit that the
+    same factor served under ``bf16`` fails; the collective counters
+    of both stats are positive."""
+    import scipy.linalg
+    from jax.sharding import NamedSharding
+    from repro import api
+    from repro.core import grid as gridlib
+
+    fails = 0
+    n, k, p1, p2 = 512, 64, 2, 1
+    limit, near = 2e-5, 1e-4          # backward error; rel. to float64
+    L = _dominant_tril(15, n)
+    B = np.random.default_rng(16).standard_normal((n, k)).astype(np.float32)
+    ref = scipy.linalg.solve_triangular(L.astype(np.float64),
+                                        B.astype(np.float64), lower=True)
+    grid = api.make_trsm_mesh(p1, p2)
+    sh = NamedSharding(grid.mesh, grid.spec_L())
+    for precision in ("bf16_refine", "fp32", "bf16"):
+        bank = api.FactorBank(grid, n, precision=precision)
+        bank.admit_cyclic(jax.device_put(
+            gridlib.to_cyclic_matrix(L, p1, p1 * p2), sh))
+        solver = api.Solver.from_bank(bank)
+        X = np.asarray(solver.solve(jnp.asarray(B), donate=False))
+        with api.AsyncSolveServer(solver, panel_k=k) as server:
+            futs = [server.submit(B[:, j]) for j in range(4)]
+            Xa = np.stack([np.asarray(f.result(timeout=300)).reshape(n)
+                           for f in futs], axis=1)
+        errs = [_backward_error(L, X, B),
+                _backward_error(L, Xa, B[:, :4])]
+        rel = max(np.abs(X - ref).max(), np.abs(Xa - ref[:, :4]).max()) \
+            / np.abs(ref).max()
+        sound = max(errs) <= limit and rel <= near
+        counted = [(st["collectives_per_solve"],
+                    st["collective_words_per_col"])
+                   for st in (solver.stats(), server.stats())]
+        ok = (not sound if precision == "bf16" else sound) \
+            and all(c > 0 and w > 0 for c, w in counted)
+        print(f"cyclic_serve p1={p1} p2={p2} n={n} n0={bank.n0} "
+              f"phase1={bank._phase1_mode} {precision}: backward error "
+              f"Solver {errs[0]:.2e}, AsyncSolveServer {errs[1]:.2e} "
+              f"(limit {limit:.0e}), max rel. to float64 {rel:.2e}; "
+              f"collectives a solve, words a column (Solver, server) "
+              f"{counted} {'OK' if ok else 'FAIL'}")
+        fails += 0 if ok else 1
     return fails
 
 
@@ -506,6 +651,7 @@ CHECKS = {
     "session": check_session,
     "bank": check_bank,
     "overlap": check_overlap,
+    "cyclic_serve": check_cyclic_serve,
 }
 
 

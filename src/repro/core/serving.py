@@ -844,7 +844,10 @@ class AsyncSolveServer:
         by the fill-independent programs), p50/p99/max
         latency (ms), the violation count when an SLO was set, and the
         per-tenant breakdown under ``"tenants"`` (submitted / served /
-        shed / deadline_shed / stranded / slo_violations each).
+        shed / deadline_shed / stranded / slo_violations each), and
+        the solve program's ``collectives_per_solve`` and
+        ``collective_words_per_col`` (:meth:`Solver.stats`; None in
+        fleet mode and before the first wave).
 
         Empty-window contract: with NO completed request in the
         window, every percentile field (``p50_ms`` / ``p99_ms`` /
@@ -858,6 +861,11 @@ class AsyncSolveServer:
             if not lat:
                 return None
             return lat[min(len(lat) - 1, int(q * len(lat)))] * 1e3
+        comm = dict(collectives_per_solve=None,
+                    collective_words_per_col=None)
+        if self.solver is not None:
+            solver = self.solver.stats()
+            comm = {key: solver[key] for key in comm}
         return dict(
             submitted=self.submitted, served=self.served,
             shed=self.shed, stranded=self.stranded, waves=self.waves,
@@ -867,7 +875,7 @@ class AsyncSolveServer:
             p50_ms=pct(0.50), p99_ms=pct(0.99),
             max_ms=lat[-1] * 1e3 if lat else None,
             slo_ms=self.slo_ms, slo_violations=self._slo_violations,
-            tenants=tenants)
+            tenants=tenants, **comm)
 
     # ------------------------- migration support -------------------------
 

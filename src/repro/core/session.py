@@ -94,6 +94,10 @@ class SolverProgram:
     Remaining fields record the resolved plan: ``method`` ("inv"/"rec"),
     ``mode`` (the inv phase-1 scheme), ``n0`` (diagonal-block size) and
     ``policy`` (the :class:`PrecisionPolicy` the program was built for).
+
+    ``collectives()`` is the :class:`repro.core.comm.CostTrace` of one
+    call of ``solve``: its body traced once on abstract operands
+    (nothing compiles or runs), memoized.
     """
     key: object                  # the program's SolveSpec (cache key)
     prep: Callable
@@ -104,6 +108,7 @@ class SolverProgram:
     mode: str | None
     n0: int | None
     policy: PrecisionPolicy
+    collectives: Callable
 
 
 class CompiledSolverCache:
@@ -401,12 +406,15 @@ def _build_solver(spec) -> SolverProgram:
         L_hi = factor[-1] if policy.refines else None
         return L_sweep, L_hi
 
-    def program(factor, B):
-        TRACE_COUNTS[key] += 1
+    def body(factor, B):
         L_sweep, L_hi = split(factor)
         return refinelib.refined_solve(base_solve, L_sweep, L_hi, B,
                                        policy=policy, p1=p1, p2=p2,
                                        reverse=rev)
+
+    def program(factor, B):
+        TRACE_COUNTS[key] += 1
+        return body(factor, B)
 
     stacked = bank is not None
     preps = _factor_preps(grid, lower, transpose, policy, stacked,
@@ -422,17 +430,31 @@ def _build_solver(spec) -> SolverProgram:
         def prep_fn(L):
             return tuple(p(L) for p in preps)
 
+    lead = (bank,) if stacked else ()
+
     def _lead(spec):
         return P(None, *spec) if stacked else spec
 
-    factor_specs = [_lead(grid.spec_L())]
+    # (shape, dtype, spec) of each factor role: L_lo[, Dt][, L_hi]
+    roles = [((n, n), policy.storage_dtype, grid.spec_L())]
     if prefactored:
-        from repro.core.inv_trsm import SPEC_DT
-        factor_specs.append(_lead(SPEC_DT))
+        from repro.core.inv_trsm import SPEC_DT, dt_shape
+        roles.append((dt_shape(n, n0), policy.storage_dtype, SPEC_DT))
     if policy.refines:
-        factor_specs.append(_lead(grid.spec_L()))
-    factor_sh = tuple(NamedSharding(grid.mesh, s) for s in factor_specs)
+        roles.append(((n, n), policy.residual_dtype, grid.spec_L()))
+    factor_sh = tuple(NamedSharding(grid.mesh, _lead(spec))
+                      for _, _, spec in roles)
     rhs_sh = NamedSharding(grid.mesh, _lead(rhs_spec))
+
+    @functools.cache
+    def collectives():
+        from repro.core import comm
+        factor = tuple(jax.ShapeDtypeStruct(lead + shape, dt, sharding=sh)
+                       for (shape, dt, _), sh in zip(roles, factor_sh))
+        B = jax.ShapeDtypeStruct(lead + (n, k), policy.io_dtype,
+                                 sharding=rhs_sh)
+        return comm.traced_cost(body, factor, B)
+
     jit_kw = dict(in_shardings=(factor_sh, rhs_sh),
                   out_shardings=rhs_sh)
     return SolverProgram(
@@ -441,7 +463,8 @@ def _build_solver(spec) -> SolverProgram:
         solve=jax.jit(program, **jit_kw),
         solve_donating=jax.jit(program, donate_argnums=(1,), **jit_kw),
         rhs_sharding=rhs_sh,
-        method=method, mode=resolved_mode, n0=n0, policy=policy)
+        method=method, mode=resolved_mode, n0=n0, policy=policy,
+        collectives=collectives)
 
 
 @dataclasses.dataclass(frozen=True)

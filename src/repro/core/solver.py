@@ -512,6 +512,7 @@ class Solver:
         self.bank = bank
         self.cache = cache if cache is not None else bank.cache
         self.solves_served = 0
+        self._served_program = None     # the program of the last solve
 
     # ---------------------------- constructors ----------------------------
 
@@ -745,6 +746,7 @@ class Solver:
         fn = prog.solve_donating if donate else prog.solve
         X = self.bank.with_stacks(fn, B)
         self.solves_served += self.width
+        self._served_program = prog
         # lax.squeeze, not X[0]: the getitem spelling lowers through
         # dynamic_slice, whose index operand is a host->device upload
         # on every call — it would break the zero-transfer steady state
@@ -765,6 +767,24 @@ class Solver:
                                 (0,))                   # lift path
             jax.lax.squeeze(X, (0,))                    # squeeze path
         return self
+
+    def stats(self) -> dict:
+        """``solves_served``, and two counters of the solve program
+        that served the last solve, taken once per program from its
+        traced collectives (``SolverProgram.collectives``):
+        ``collectives_per_solve``, the collectives one call issues
+        over more than one device, and ``collective_words_per_col``,
+        the words they move on the critical path (the paper's W) per
+        right-hand-side column.  Both are 0 on a (1, 1) mesh and None
+        before the first solve."""
+        prog = self._served_program
+        count = words = None
+        if prog is not None:
+            cost = prog.collectives()
+            count, words = cost.count, cost.w / prog.key.k
+        return dict(solves_served=self.solves_served,
+                    collectives_per_solve=count,
+                    collective_words_per_col=words)
 
     # ------------------------- live bank mutation -------------------------
 

@@ -40,6 +40,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 
 from repro.core import blocked, comm
+from repro.core import grid as gridlib
 from repro.core.grid import TrsmGrid
 from repro.core.mm3d import mm3d_shard_batched
 
@@ -58,33 +59,24 @@ def _diag_pieces(Lloc, m: int):
 def _assemble_blocks(Dg, p1: int, p2: int):
     """(p, m, a, b) gathered pieces (x-major flattened device axis) ->
     (m, a*p1, b*p1*p2) full blocks in natural element order."""
-    p, m, a, b = Dg.shape
-    R = Dg.reshape(p1, p1, p2, m, a, b)            # [x, y, z, i, l, c']
-    R = jnp.transpose(R, (3, 4, 0, 5, 2, 1))       # [i, l, x, c', z, y]
-    return R.reshape(m, a * p1, b * p2 * p1)
+    return gridlib.assemble_blocks(Dg, p1, p2)
 
 
 def _cyclic_piece(blocks, x, y, z, p1: int, p2: int):
     """(m, s, s) full blocks -> this device's cyclic piece
     (rows r = l*p1 + x, cols c = c'*p1*p2 + z*p1 + y): (m, s/p1, s/(p1p2)).
     x, y, z may be traced scalars."""
-    m, s, _ = blocks.shape
-    a, b = s // p1, s // (p1 * p2)
-    R = blocks.reshape(m, a, p1, b, p2, p1)        # [i, l, x, c', z, y]
-    R = jnp.moveaxis(R, (2, 4, 5), (0, 1, 2))      # [x, z, y, i, l, c']
-    R = jax.lax.dynamic_index_in_dim(R, x, axis=0, keepdims=False)
-    R = jax.lax.dynamic_index_in_dim(R, z, axis=0, keepdims=False)
-    return jax.lax.dynamic_index_in_dim(R, y, axis=0, keepdims=False)
+    return gridlib.block_piece(blocks, x, z * p1 + y, p1, p1 * p2)
 
 
 def _pieces_for_all(blocks, p1: int, p2: int):
     """(m, s, s) full blocks -> (p, m, s/p1, s/(p1p2)) cyclic pieces for
     every destination device, x-major device order."""
     m, s, _ = blocks.shape
-    a, b = s // p1, s // (p1 * p2)
-    R = blocks.reshape(m, a, p1, b, p2, p1)        # [i, l, x, c', z, y]
-    R = jnp.transpose(R, (2, 5, 4, 0, 1, 3))       # [x, y, z, i, l, c']
-    return R.reshape(p1 * p1 * p2, m, a, b)
+    R = gridlib.block_pieces(blocks, p1, p1 * p2)  # [x, (z, y), i, l, c']
+    R = R.reshape(p1, p2, p1, m, s // p1, s // (p1 * p2))
+    R = jnp.transpose(R, (0, 2, 1, 3, 4, 5))       # [x, y, z, i, l, c']
+    return R.reshape(p1 * p1 * p2, m, s // p1, s // (p1 * p2))
 
 
 # --------------------------- phase A ---------------------------

@@ -28,7 +28,8 @@ pytestmark = pytest.mark.slow
 @pytest.mark.parametrize("check", ["order", "phase1_z", "mm3d", "tri_inv",
                                    "rec_trsm",
                                    "it_inv_trsm", "doubling", "cholesky",
-                                   "lu", "session", "bank", "overlap"])
+                                   "lu", "session", "bank", "overlap",
+                                   "cyclic_serve"])
 def test_selfcheck(check):
     out = run_selfcheck(check)
     assert "FAIL" not in out

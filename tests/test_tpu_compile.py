@@ -69,8 +69,11 @@ def _fits(compiled):
 @pytest.mark.parametrize("p1,p2,n", [(1, 1, N_CHIP), (1, 4, N_MESH),
                                      (2, 1, N_MESH)])
 def test_served_programs_compile_for_v5e(topo, p1, p2, n):
-    """Admission's phase-1 program, the sweep, and one refined solve
-    program, at the plan the front door picks (``tuning.serving_n0``)."""
+    """Admission's phase-1 program, the sweep, and the refined solve
+    program, at the plan the front door picks (``tuning.serving_n0``);
+    on (2, 1), the mesh of the four-chip cell, also at its panel width
+    4096."""
+    ks = (K, 4096) if (p1, p2) == (2, 1) else (K,)
     grid = _grid(topo, p1, p2)
     n0 = tuning.serving_n0(n, grid)
     pol = PRESETS["bf16_refine"]
@@ -89,18 +92,39 @@ def test_served_programs_compile_for_v5e(topo, p1, p2, n):
                            inv_trsm.SPEC_DT),
                       _sds(grid, (n, K), f32, grid.spec_B())).compile())
 
-    prog = session._build_solver(SolveSpec(
-        n=n, k=K, grid=grid, policy=pol, method="inv", n0=n0,
-        bank_width=1))
     lead = [P(None, *grid.spec_L()), P(None, *inv_trsm.SPEC_DT),
             P(None, *grid.spec_L())]
     shapes = [(1, n, n), (1,) + inv_trsm.dt_shape(n, n0), (1, n, n)]
     dts = [pol.storage_dtype, pol.storage_dtype, pol.residual_dtype]
     factor = tuple(_sds(grid, s, d, sp)
                    for s, d, sp in zip(shapes, dts, lead))
-    rhs = jax.ShapeDtypeStruct((1, n, K), pol.io_dtype,
-                               sharding=prog.rhs_sharding)
-    _fits(prog.solve_donating.lower(factor, rhs).compile())
+    for k in ks:
+        prog = session._build_solver(SolveSpec(
+            n=n, k=k, grid=grid, policy=pol, method="inv", n0=n0,
+            bank_width=1))
+        rhs = jax.ShapeDtypeStruct((1, n, k), pol.io_dtype,
+                                   sharding=prog.rhs_sharding)
+        _fits(prog.solve_donating.lower(factor, rhs).compile())
+
+
+def test_phase1_on_2x2_needs_no_padded_copy(topo):
+    """Phase 1 on mesh (2, 1) at the plan the front door picks: its
+    scratch stays within a small multiple of its operands.  Assembling
+    whole diagonal blocks by a reshape and transpose put a size-2 mesh
+    axis minor-most, which the (8, 128) tiling pads 64-fold: 22x the
+    operands here at n = 8192, and a 64 GiB copy at n = 65536."""
+    grid = _grid(topo, 2, 1)
+    n = 8192
+    n0 = tuning.serving_n0(n, grid)
+    pol = PRESETS["bf16_refine"]
+    ph1 = session._build_phase1(grid, n, n0,
+                                inv_trsm.pick_phase1_mode(n, n0, grid),
+                                pol.accumulate_dtype, None)
+    m = ph1.lower(_sds(grid, (n, n), pol.storage_dtype, grid.spec_L())
+                  ).compile().memory_analysis()
+    operands = m.argument_size_in_bytes + m.output_size_in_bytes
+    assert m.temp_size_in_bytes <= 4 * operands, (m.temp_size_in_bytes,
+                                                  operands)
 
 
 def test_unit_wave_programs_compile_for_v5e(topo):

@@ -147,3 +147,52 @@ def test_wave_spans_nest_in_dispatch(traced):
                   "trsm.wave.slice"):
         assert len(by[child]) == len(by["trsm.dispatch"]), child
         assert all(_inside(c, by["trsm.dispatch"]) for c in by[child])
+
+
+def test_admit_spans_nest(tmp_path):
+    """Admission under a trace, natural and cyclic: ``trsm.admit``
+    holds the ingestion (one gather, or one cast per resident dtype)
+    and phase 1, and the record keeps what the trace holds."""
+    rng = np.random.default_rng(1)
+    grid = api.make_trsm_mesh(1, 1)
+    bank = api.FactorBank(grid, N, n0=8, precision="bf16_refine")
+    L = _factor(rng)
+    bank.admit(L)                               # programs compiled
+    bank.admit_cyclic(L)        # on (1, 1) cyclic storage is natural
+    spans.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    bank.admit(L)
+    bank.admit_cyclic(L)
+    jax.profiler.stop_trace()
+    kept = spans.recorded()
+    spans.clear()
+    events = [ev for ev in _host_spans(str(tmp_path))
+              if ev[0].startswith("trsm.admit")]
+    by = collections.defaultdict(list)
+    for ev in events:
+        by[ev[0]].append(ev)
+    assert len(by["trsm.admit"]) == 2
+    assert len(by["trsm.admit.phase1"]) == 2
+    assert len(by["trsm.admit.ingest"]) == 3    # a gather; two casts
+    for child in ("trsm.admit.ingest", "trsm.admit.phase1"):
+        assert all(_inside(c, by["trsm.admit"]) for c in by[child])
+    assert collections.Counter(r[0] for r in kept) \
+        == collections.Counter(ev[0] for ev in events)
+
+
+def test_collective_counters_zero_on_one_device():
+    """``collectives_per_solve`` and ``collective_words_per_col`` are
+    None before the first solve and 0 on a (1, 1) mesh, in the
+    Solver's stats and the server's (positive on (2, 1): selfcheck
+    ``cyclic_serve``)."""
+    rng = np.random.default_rng(2)
+    solver = api.Solver.from_factor(_factor(rng), api.make_trsm_mesh(1, 1),
+                                    n0=8, precision="bf16_refine")
+    assert solver.stats()["collectives_per_solve"] is None
+    srv = api.AsyncSolveServer(solver, PANEL).warmup()
+    with srv:
+        srv.submit(rng.standard_normal(N).astype(np.float32)).result(
+            timeout=60)
+    for stats in (solver.stats(), srv.stats()):
+        assert stats["collectives_per_solve"] == 0
+        assert stats["collective_words_per_col"] == 0
