@@ -26,10 +26,18 @@ Everything here is designed to live INSIDE the one compiled program of
 
       op(A) X  =  unpermute_rows( L_cyc @ permute_rows(X, col-map) )
 
-  i.e. two O(nk) on-device gathers around one GEMM — no second layout,
-  no host permutation, and the SAME expression serves all four
+  i.e. two O(nk) on-device gathers around one product — no second
+  layout, no host permutation, and the SAME expression serves all four
   (lower, transpose) operator variants because the reduction identities
-  are already folded into ``L_cyc``'s gathers.
+  are already folded into ``L_cyc``'s gathers;
+* on one device (p1 * p2 == 1) both cyclic maps are the identity (or a
+  reversal that turns an upper factor lower), so ``L_cyc`` is lower
+  triangular and the product is ROW-BLOCKED: each block of rows
+  multiplies only the columns up to its diagonal tile, about
+  (T+1)/(2T) of the dense GEMM's multiply-adds for T blocks, at the
+  same precision.  On a larger mesh the global ``L_cyc`` is a grid of
+  interleaved triangular quadrants that GSPMD shards; slicing it
+  globally would reshard, so there the product stays one dense GEMM.
 """
 
 from __future__ import annotations
@@ -39,6 +47,64 @@ import jax.numpy as jnp
 
 from repro.core import grid as gridlib
 from repro.core.precision import PrecisionPolicy, gemm_precision
+
+
+# The one-chip residual's row blocks: ``_ROW_BLOCKS`` blocks of a whole
+# number of ``_ALIGN`` rows (the MXU's tile), the last one ragged; below
+# ``_MIN_BLOCK_ROWS`` rows a block the dense product is used instead.
+# 16 blocks beat 4 and 8 on a v5e at n = 32768 for k = 4096 and k = 256:
+# the blocked dots run at the dense dot's rate, so fewer multiply-adds
+# win (PERF.md, section 6).
+_ROW_BLOCKS = 16
+_ALIGN = 128
+_MIN_BLOCK_ROWS = 512
+
+
+def residual_row_bounds(n: int) -> tuple[int, ...]:
+    """End row (exclusive) of each row block of the one-chip residual
+    product of order ``n``; ``(n,)`` is the dense product."""
+    rows = -(-n // _ROW_BLOCKS)
+    rows = -(-rows // _ALIGN) * _ALIGN
+    if rows < _MIN_BLOCK_ROWS:
+        return (n,)
+    return tuple(range(rows, n, rows)) + (n,)
+
+
+def _row_bounds(n: int, p1: int, p2: int) -> tuple[int, ...]:
+    return residual_row_bounds(n) if p1 * p2 == 1 else (n,)
+
+
+def residual_tile_share(n: int, p1: int, p2: int) -> float:
+    """The share of the dense (n, n) residual GEMM's multiply-adds that
+    :func:`apply_cyclic_operator` does on a (p1, p2) mesh: (T+1)/(2T)
+    for T equal row blocks, 1.0 for the dense product."""
+    bounds = _row_bounds(n, p1, p2)
+    starts = (0,) + bounds[:-1]
+    return sum((e - s) * e for s, e in zip(starts, bounds)) / (n * n)
+
+
+def lower_product(L, X, bounds, *, precision, preferred_element_type):
+    """``L @ X`` for a lower-triangular ``L`` (n, n), or a stack of them
+    (M, n, n) with X (M, n, k): the rows split at ``bounds`` (end row of
+    each block, last == n), block I multiplying ``L[rows_I, :end_I]``
+    by ``X[:end_I]``.  No tile above the diagonal tiles is read, so the
+    result is the dense product's in exact arithmetic (only the
+    summation order differs) PROVIDED L is zero above its diagonal —
+    which the solver assumes of every lower factor anyway: admission
+    applies no ``tril``.  ``bounds == (n,)`` is the one dense GEMM."""
+    kw = dict(precision=precision,
+              preferred_element_type=preferred_element_type)
+
+    def dot(a, b):
+        if a.ndim == 2:
+            return jax.lax.dot(a, b, **kw)
+        return jax.lax.dot_general(
+            a, b, dimension_numbers=(((2,), (1,)), ((0,), (0,))), **kw)
+    if len(bounds) == 1:
+        return dot(L, X)
+    starts = (0,) + tuple(bounds[:-1])
+    return jnp.concatenate([dot(L[..., s:e, :e], X[..., :e, :])
+                            for s, e in zip(starts, bounds)], axis=-2)
 
 
 def apply_cyclic_operator(L_cyc, X, *, p1: int, p2: int, reverse: bool,
@@ -52,27 +118,26 @@ def apply_cyclic_operator(L_cyc, X, *, p1: int, p2: int, reverse: bool,
 
         op(A) X = G_r^{-1} ( L_cyc @ G_c X )
 
-    — one gather of X's rows by the factor's COLUMN map, the GEMM
+    — one gather of X's rows by the factor's COLUMN map, the product
     against the resident factor, and the inverse gather by the factor's
     ROW map.  The transpose flag needs no case here: it was applied to
     the matrix before distribution, so it is part of op(A) already.
 
+    On a (1, 1) mesh ``L_cyc`` is lower triangular and the product is
+    :func:`lower_product` over :func:`residual_row_bounds`; on a larger
+    mesh it is one dense GEMM (module docstring).
+
     Accepts stacked operands too — L_cyc (M, n, n) with X (M, n, k) —
     in which case the gathers permute the trailing row axis and the
-    GEMM is one batched contraction: a factor bank's whole refinement
-    residual is three ops (DESIGN.md Sec. 9).
+    product is batched: a factor bank's whole refinement residual is
+    the same few ops (DESIGN.md Sec. 9).
     """
     Xg = gridlib.cyclic_rows_device(X, p1 * p2, reverse=reverse)
     acc = jnp.dtype(accum_dtype) if accum_dtype is not None else X.dtype
     Xg = Xg.astype(L_cyc.dtype)
-    hp = gemm_precision(L_cyc, Xg)
-    if L_cyc.ndim == 2:
-        Y = jax.lax.dot(L_cyc, Xg, precision=hp,
-                        preferred_element_type=acc)
-    else:
-        Y = jax.lax.dot_general(
-            L_cyc, Xg, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            precision=hp, preferred_element_type=acc)
+    Y = lower_product(L_cyc, Xg, _row_bounds(L_cyc.shape[-1], p1, p2),
+                      precision=gemm_precision(L_cyc, Xg),
+                      preferred_element_type=acc)
     return gridlib.cyclic_rows_device(Y, p1, inverse=True, reverse=reverse)
 
 

@@ -638,6 +638,63 @@ def check_cyclic_serve() -> int:
     return fails
 
 
+def _dense_residual(L_cyc, X, *, p1, p2, reverse, accum_dtype=None):
+    """The refinement residual as one dense GEMM (the reference the
+    meshes past one device must still compile to)."""
+    from repro.core import grid as gridlib
+    from repro.core.precision import gemm_precision
+    Xg = gridlib.cyclic_rows_device(X, p1 * p2, reverse=reverse)
+    acc = jnp.dtype(accum_dtype) if accum_dtype is not None else X.dtype
+    Xg = Xg.astype(L_cyc.dtype)
+    Y = jax.lax.dot_general(
+        L_cyc, Xg, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=gemm_precision(L_cyc, Xg), preferred_element_type=acc)
+    return gridlib.cyclic_rows_device(Y, p1, inverse=True, reverse=reverse)
+
+
+def check_residual_dense() -> int:
+    """Past one device the refinement residual stays one dense GEMM: a
+    served bf16_refine program of order 8192 (sixteen row blocks on one
+    device) lowers to exactly the program built with the dense
+    residual, and ``residual_tile_share`` reads 1.0 in both stats."""
+    from repro import api
+    from repro.core import refine, session
+
+    fails = 0
+    n, k = 8192, 8
+    L = _dominant_tril(21, n)
+    B = np.random.default_rng(22).standard_normal((n, k)).astype(
+        np.float32)
+    for p1, p2 in [(2, 1), (1, 2)]:
+        grid = api.make_trsm_mesh(p1, p2)
+        bank = api.FactorBank(grid, n, precision="bf16_refine")
+        bank.admit(jnp.asarray(L))
+        solver = api.Solver.from_bank(bank)
+        with api.AsyncSolveServer(solver, panel_k=k) as server:
+            server.submit(B[:, 0]).result(timeout=300)
+        prog = solver.program_for(k)
+        Bp = solver.place_rhs(B)
+        text = prog.solve.lower(bank.stacks(), Bp).as_text()
+        orig = refine.apply_cyclic_operator
+        refine.apply_cyclic_operator = _dense_residual    # traced in lower
+        try:
+            ref = session._build_solver(prog.key).solve.lower(
+                bank.stacks(), Bp).as_text()
+        finally:
+            refine.apply_cyclic_operator = orig
+        same = ref == text
+        shares = [st["residual_tile_share"]
+                  for st in (solver.stats(), server.stats())]
+        blocked = len(refine.residual_row_bounds(n)) > 1
+        ok = same and blocked and shares == [1.0, 1.0]
+        print(f"residual_dense p1={p1} p2={p2} n={n}: lowers to the dense"
+              f" residual's program {same}; blocked on one device "
+              f"{blocked}; residual_tile_share (Solver, server) {shares} "
+              f"{'OK' if ok else 'FAIL'}")
+        fails += 0 if ok else 1
+    return fails
+
+
 CHECKS = {
     "order": check_collective_order,
     "phase1_z": check_phase1_z,
@@ -652,6 +709,7 @@ CHECKS = {
     "bank": check_bank,
     "overlap": check_overlap,
     "cyclic_serve": check_cyclic_serve,
+    "residual_dense": check_residual_dense,
 }
 
 

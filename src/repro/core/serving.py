@@ -845,9 +845,10 @@ class AsyncSolveServer:
         latency (ms), the violation count when an SLO was set, and the
         per-tenant breakdown under ``"tenants"`` (submitted / served /
         shed / deadline_shed / stranded / slo_violations each), and
-        the solve program's ``collectives_per_solve`` and
-        ``collective_words_per_col`` (:meth:`Solver.stats`; None in
-        fleet mode and before the first wave).
+        the solve program's ``collectives_per_solve``,
+        ``collective_words_per_col`` and ``residual_tile_share``
+        (:meth:`Solver.stats`; None in fleet mode and before the
+        first wave).
 
         Empty-window contract: with NO completed request in the
         window, every percentile field (``p50_ms`` / ``p99_ms`` /
@@ -862,7 +863,8 @@ class AsyncSolveServer:
                 return None
             return lat[min(len(lat) - 1, int(q * len(lat)))] * 1e3
         comm = dict(collectives_per_solve=None,
-                    collective_words_per_col=None)
+                    collective_words_per_col=None,
+                    residual_tile_share=None)
         if self.solver is not None:
             solver = self.solver.stats()
             comm = {key: solver[key] for key in comm}

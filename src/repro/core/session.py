@@ -97,7 +97,11 @@ class SolverProgram:
 
     ``collectives()`` is the :class:`repro.core.comm.CostTrace` of one
     call of ``solve``: its body traced once on abstract operands
-    (nothing compiles or runs), memoized.
+    (nothing compiles or runs), memoized.  ``residual_tile_share`` is
+    the share of the dense refinement residual's multiply-adds that
+    ``solve`` does (``refine.residual_tile_share``: below 1 where the
+    residual is row-blocked, on one device), None when the policy does
+    not refine.
     """
     key: object                  # the program's SolveSpec (cache key)
     prep: Callable
@@ -109,6 +113,7 @@ class SolverProgram:
     n0: int | None
     policy: PrecisionPolicy
     collectives: Callable
+    residual_tile_share: float | None
 
 
 class CompiledSolverCache:
@@ -464,7 +469,9 @@ def _build_solver(spec) -> SolverProgram:
         solve_donating=jax.jit(program, donate_argnums=(1,), **jit_kw),
         rhs_sharding=rhs_sh,
         method=method, mode=resolved_mode, n0=n0, policy=policy,
-        collectives=collectives)
+        collectives=collectives,
+        residual_tile_share=refinelib.residual_tile_share(n, p1, p2)
+        if policy.refines else None)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -776,15 +776,20 @@ class Solver:
         over more than one device, and ``collective_words_per_col``,
         the words they move on the critical path (the paper's W) per
         right-hand-side column.  Both are 0 on a (1, 1) mesh and None
-        before the first solve."""
+        before the first solve.  ``residual_tile_share``: the share of
+        the dense refinement residual's multiply-adds that program
+        does (``SolverProgram.residual_tile_share``; None also when
+        the policy does not refine)."""
         prog = self._served_program
-        count = words = None
+        count = words = share = None
         if prog is not None:
             cost = prog.collectives()
             count, words = cost.count, cost.w / prog.key.k
+            share = prog.residual_tile_share
         return dict(solves_served=self.solves_served,
                     collectives_per_solve=count,
-                    collective_words_per_col=words)
+                    collective_words_per_col=words,
+                    residual_tile_share=share)
 
     # ------------------------- live bank mutation -------------------------
 

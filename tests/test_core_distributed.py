@@ -29,7 +29,7 @@ pytestmark = pytest.mark.slow
                                    "rec_trsm",
                                    "it_inv_trsm", "doubling", "cholesky",
                                    "lu", "session", "bank", "overlap",
-                                   "cyclic_serve"])
+                                   "cyclic_serve", "residual_dense"])
 def test_selfcheck(check):
     out = run_selfcheck(check)
     assert "FAIL" not in out

@@ -101,17 +101,37 @@ def test_fp64_policy_requires_x64(grid):
 
 # ----------------------- the refinement operator -----------------------
 
-@pytest.mark.parametrize("lower,transpose", [(True, False), (False, False),
-                                             (True, True), (False, True)])
-def test_apply_cyclic_operator_matches_dense(lower, transpose):
+_VARIANTS = [(True, False), (False, False), (True, True), (False, True)]
+# (p1, p2, n, stack): the dense product behind stride-4 cyclic maps
+# (ids kept as the variant alone), and the one-device row-blocked
+# product with even and ragged blocks, on single and stacked operands
+_OPERATOR_CASES = [
+    pytest.param(lower, transpose, p1, p2, n, m,
+                 id=f"{lower}-{transpose}" if p1 > 1 else
+                 f"{lower}-{transpose}-1x1-n{n}-m{m}")
+    for p1, p2, n, m in [(2, 2, 32, None), (1, 1, 32, None),
+                         (1, 1, 37, None), (1, 1, 37, 3)]
+    for lower, transpose in _VARIANTS]
+
+
+@pytest.mark.parametrize("lower,transpose,p1,p2,n,m", _OPERATOR_CASES)
+def test_apply_cyclic_operator_matches_dense(lower, transpose, p1, p2, n,
+                                             m, monkeypatch):
     """op(A) @ X reconstructed from the RESIDENT cyclic factor must
-    equal the dense product, for every operator reduction variant."""
-    n, k, p1, p2 = 32, 5, 2, 2
+    equal the dense product, for every operator reduction variant; on
+    one device through the row-blocked product (blocks of 8 rows here,
+    engaged by shrinking the block rule's alignment and minimum)."""
+    monkeypatch.setattr(refine, "_ALIGN", 8)
+    monkeypatch.setattr(refine, "_MIN_BLOCK_ROWS", 8)
+    if p1 * p2 == 1:
+        assert len(refine.residual_row_bounds(n)) > 1
+    k = 5
     rng = np.random.default_rng(4)
-    L = np.tril(rng.standard_normal((n, n))) + np.eye(n)
-    A = L if lower else L.T
-    op = A.T if transpose else A
-    X = rng.standard_normal((n, k))
+    lead = () if m is None else (m,)
+    L = np.tril(rng.standard_normal(lead + (n, n))) + np.eye(n)
+    A = L if lower else np.swapaxes(L, -1, -2)
+    op = np.swapaxes(A, -1, -2) if transpose else A
+    X = rng.standard_normal(lead + (n, k))
     rev = lower == transpose
     L_cyc = gridlib.cyclic_matrix_device(
         jnp.asarray(A), p1, p1 * p2, reverse_rows=rev, reverse_cols=rev,
@@ -119,6 +139,29 @@ def test_apply_cyclic_operator_matches_dense(lower, transpose):
     got = refine.apply_cyclic_operator(L_cyc, jnp.asarray(X),
                                        p1=p1, p2=p2, reverse=rev)
     np.testing.assert_allclose(np.asarray(got), op @ X, atol=1e-10)
+
+
+@pytest.mark.parametrize("n,bounds", [
+    (32768, tuple(range(2048, 32769, 2048))),
+    (10000, tuple(range(640, 9601, 640)) + (10000,)),  # ragged last block
+    (8192, tuple(range(512, 8193, 512))),               # the smallest blocks
+    (8000, tuple(range(512, 7681, 512)) + (8000,)),
+    (5000, (5000,)),                       # blocks under 512 rows: dense
+    (1, (1,)),
+])
+def test_residual_row_bounds(n, bounds):
+    """Sixteen blocks of a whole number of 128 rows, the last ragged;
+    the dense product where a block would be under 512 rows.  The tile
+    share counts the multiply-adds the blocks do: (T+1)/(2T) for T
+    equal blocks, 1 for the dense product and on every larger mesh."""
+    assert refine.residual_row_bounds(n) == bounds
+    starts = (0,) + bounds[:-1]
+    assert all(e - s <= bounds[0] for s, e in zip(starts, bounds))
+    share = sum((e - s) * e for s, e in zip(starts, bounds)) / n ** 2
+    assert refine.residual_tile_share(n, 1, 1) == pytest.approx(share)
+    assert refine.residual_tile_share(n, 2, 1) == 1.0
+    if n == 32768:
+        assert share == 17 / 32
 
 
 @pytest.mark.parametrize("method", ["inv", "rec"])

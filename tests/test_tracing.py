@@ -196,3 +196,31 @@ def test_collective_counters_zero_on_one_device():
     for stats in (solver.stats(), srv.stats()):
         assert stats["collectives_per_solve"] == 0
         assert stats["collective_words_per_col"] == 0
+
+
+@pytest.mark.parametrize("precision,share", [("bf16_refine", 17 / 32),
+                                             ("fp32", None)])
+def test_residual_tile_share_on_one_device(precision, share):
+    """``residual_tile_share`` is None before the first solve; on a
+    (1, 1) mesh at order 8192 the bf16_refine residual is sixteen row
+    blocks, (T+1)/(2T) = 17/32 of the dense GEMM's multiply-adds, and
+    the program holds sixteen f32 products a pass; ``fp32`` does not
+    refine: None (1.0 on (2, 1): selfcheck ``residual_dense``)."""
+    n, k = 8192, 4
+    L = (np.tril(np.random.default_rng(3).uniform(-0.5, 0.5, (n, n)), -1)
+         + n * np.eye(n)).astype(np.float32)
+    solver = api.Solver.from_factor(L, api.make_trsm_mesh(1, 1),
+                                    precision=precision)
+    assert solver.stats()["residual_tile_share"] is None
+    srv = api.AsyncSolveServer(solver, k).warmup()
+    with srv:
+        srv.submit(np.ones(n, np.float32)).result(timeout=120)
+    for stats in (solver.stats(), srv.stats()):
+        assert stats["residual_tile_share"] == share
+    if share is not None:
+        prog = solver.program_for(k)
+        text = prog.solve.lower(solver.bank.stacks(), solver.place_rhs(
+            np.zeros((n, k), np.float32))).as_text()
+        highest = [ln for ln in text.splitlines() if "dot_general" in ln
+                   and "precision = [HIGHEST, HIGHEST]" in ln]
+        assert len(highest) == 16 * prog.policy.refine_steps
