@@ -29,16 +29,6 @@ BENCHES = [
      "benchmarks.bench_stability"),
     ("gemm_fraction", "TPU MXU-eligible flop share",
      "benchmarks.bench_gemm_fraction"),
-    ("serve_latency", "device-resident solve pipeline latency",
-     "benchmarks.bench_serve_latency"),
-    ("bank", "multi-factor batched serving (FactorBank)",
-     "benchmarks.bench_bank"),
-    ("update", "live bank mutation (in-place replace vs rebuild)",
-     "benchmarks.bench_update"),
-    ("fleet", "mixed-order serving (fleet buckets vs per-order banks)",
-     "benchmarks.bench_fleet"),
-    ("structure", "structured factors (banded vs dense sweep)",
-     "benchmarks.bench_structure"),
 ]
 
 

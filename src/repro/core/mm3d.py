@@ -28,7 +28,7 @@ Schedule (paper Alg. MM, adapted to the 3D mesh — see DESIGN.md):
 Our mesh-native layout removes the paper's lines 3 and 8 (the two
 rectangular-grid transposes costing O(nk log(p)/p)): the reduce-scatter
 lands directly on the input layout.  This is a (constant/log-factor)
-improvement recorded in EXPERIMENTS.md; the leading-order cost matches
+improvement (DESIGN.md Sec. 8.2); the leading-order cost matches
 the paper exactly:  W = mn/p1^2 * 1_{p2} + 2nk/(p1 p2).
 """
 

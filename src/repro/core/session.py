@@ -196,8 +196,7 @@ class CompiledSolverCache:
 
     def stats(self) -> dict:
         """Observability snapshot: size/hits/misses/evictions plus the
-        derived hit rate (surfaced by ``launch.serve --cache-stats``
-        and recorded by benchmarks/bench_serve_latency.py)."""
+        derived hit rate (surfaced by ``launch.serve --cache-stats``)."""
         with self._lock:
             total = self.hits + self.misses
             return dict(size=len(self._entries), hits=self.hits,

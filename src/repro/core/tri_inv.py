@@ -20,7 +20,7 @@ mirror of the recursion tree executed level by level from the leaves:
            blocks; the batch plays the role of the paper's disjoint
            subgrids — all p processors cooperate on all blocks, which
            achieves a slightly *lower* bandwidth constant than the
-           paper's shrinking-subgrid scheme; see EXPERIMENTS.md).
+           paper's shrinking-subgrid scheme; see DESIGN.md Sec. 8.3).
 
 Latency is O(log(n/s0)) levels x O(log p) per level = O(log^2 p) — the
 paper's headline polylog synchronization — and the flop/bandwidth costs

@@ -110,8 +110,7 @@ def roles(mesh: Mesh, mode: str = "2d"):
     "fsdp_all": pure ZeRO-3 — parameters sharded over data x model, no
         tensor parallelism; activations take the model axis as sequence
         parallelism (see batch_specs).  Kills the per-layer TP
-        reductions — the hillclimb lever for small collective-bound
-        models (EXPERIMENTS.md Sec. Perf)."""
+        reductions, which suits small collective-bound models."""
     if mode == "2d":
         return "data", "model"
     if mode == "fsdp_all":

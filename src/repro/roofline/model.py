@@ -7,8 +7,7 @@ counts.  The dry-run therefore records BOTH the raw compiled numbers
 (structural evidence: the collective op set, per-iteration payloads,
 memory fit) and this analytic model, which is exact for flops (validated
 against an UNROLLED smoke compile in tests/test_roofline.py) and
-first-order for HBM/collective traffic.  The roofline tables in
-EXPERIMENTS.md use the analytic terms.
+first-order for HBM/collective traffic.
 
 All formulas are per STEP and GLOBAL; ``per_device`` divides by chip
 count at the end.  dtype = bf16 compute (2 bytes), f32 optimizer state.

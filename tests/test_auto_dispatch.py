@@ -1,6 +1,5 @@
 """method='auto' dispatch: the alpha-beta-gamma model instantiated with
-machine constants picks the right algorithm per (shape, network) —
-EXPERIMENTS.md Sec. Perf cell C."""
+machine constants picks the right algorithm per (shape, network)."""
 
 import pytest
 
